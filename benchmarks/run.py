@@ -36,8 +36,9 @@ def provenance() -> dict:
         pass
     tier1 = None
     try:
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # The collecting child must never ask for an accelerator: this
+        # process may already hold it.
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (os.path.join(_REPO_ROOT, "src"),
                         env.get("PYTHONPATH")) if p

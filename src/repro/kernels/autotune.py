@@ -15,8 +15,8 @@ Callers that pass explicit block sizes bypass the registry entirely.
 
 The second half of the recipe is the persistent JAX compilation cache
 (:func:`enable_compilation_cache`): repeat benches and relaunches skip XLA
-recompiles entirely.  Opt-in (env ``REPRO_JAX_CACHE=1`` via ``launch/env.py``
-or a direct call) because it writes outside the repo.
+recompiles entirely.  It lives where ``JAX_COMPILATION_CACHE_DIR`` says, and
+otherwise at ``<checkout>/.jax_cache`` (gitignored).
 """
 
 from __future__ import annotations
@@ -27,13 +27,22 @@ import os
 
 __all__ = [
     "shape_bucket", "registry_key", "lookup", "load_registry",
-    "save_registry", "REGISTRY_PATH", "enable_compilation_cache",
+    "save_registry", "REGISTRY_PATH", "CACHE_DIR", "enable_compilation_cache",
 ]
 
 #: The checked-in winners (regenerate with
 #: ``python -m benchmarks.bench_kernels --update-registry``).
 REGISTRY_PATH = os.path.join(os.path.dirname(__file__),
                              "autotune_registry.json")
+
+#: Default persistent-cache directory.  Fixed to the checkout, not the
+#: working directory: the path is part of the cache's key, so a directory
+#: that moves with the cwd never hits.
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
 
 
 def shape_bucket(dims: dict[str, int]) -> str:
@@ -51,16 +60,9 @@ def shape_bucket(dims: dict[str, int]) -> str:
 def registry_key(op: str, dims: dict[str, int],
                  backend: str | None = None) -> str:
     if backend is None:
-        backend = _default_backend()
-    return f"{op}|{backend}|{shape_bucket(dims)}"
-
-
-def _default_backend() -> str:
-    try:
         import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+        backend = jax.default_backend()
+    return f"{op}|{backend}|{shape_bucket(dims)}"
 
 
 @functools.lru_cache(maxsize=1)
@@ -89,23 +91,19 @@ def lookup(op: str, dims: dict[str, int],
     return entry.get("blocks", {})
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default:
-    ``$REPRO_JAX_CACHE_DIR`` or ``.jax_cache`` under the working directory —
-    kept inside the checkout, gitignored).  Thresholds drop to zero so even
-    the small test-shape kernels are cached.  Returns the cache dir, or None
-    when this JAX build has no persistent cache support."""
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            "REPRO_JAX_CACHE_DIR",
-            os.path.join(os.getcwd(), ".jax_cache"),
-        )
-    try:
-        import jax
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and no
+    directory is set here; otherwise the cache goes to :data:`CACHE_DIR`.
+    Thresholds drop to zero so even the small test-shape kernels are cached."""
+    import jax
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        return None
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return cache_dir

@@ -11,6 +11,10 @@ once, the state never leaves.
 
 Inputs are pre-flattened to (B*H, S, ·) and dt-premultiplied by ops.py; decay
 logs ``la = dt * A <= 0`` keep every exp() argument non-positive (stable).
+``la`` is carried as a (B*H, S, 1) column: Mosaic requires a block's last two
+dims to divide (8, 128) or equal the array's, which a (1, chunk) block of a
+2-D array does not.  Mosaic has no cumsum either, so the in-chunk prefix sum
+is a lower-triangular matmul.
 """
 
 from __future__ import annotations
@@ -29,30 +33,37 @@ def _ssd_kernel(xdt_ref, la_ref, b_ref, c_ref, y_ref, state_ref, h_ref, *, chunk
         h_ref[...] = jnp.zeros_like(h_ref)
 
     xdt = xdt_ref[0].astype(jnp.float32)          # (c, P)
-    la = la_ref[0].astype(jnp.float32)            # (c,)
+    la = la_ref[0].astype(jnp.float32)            # (c, 1)
     bmat = b_ref[0].astype(jnp.float32)           # (c, N)
     cmat = c_ref[0].astype(jnp.float32)           # (c, N)
-    cum = jnp.cumsum(la)                          # inclusive prefix logs
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = (ii >= jj).astype(jnp.float32)
+    cum = jax.lax.dot_general(                    # (c, 1) inclusive prefix logs
+        # HIGHEST: a bf16 pass would round the logs before exp() amplifies.
+        tri, la, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     # Intra-chunk quadratic form: S[i,j] = (C_i·B_j) exp(cum_i - cum_j), j<=i.
     g = jax.lax.dot_general(                      # (c, c)
         cmat, bmat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    logw = cum[:, None] - cum[None, :]
+    logw = cum - cum.T
     s_mat = jnp.where(ii >= jj, g * jnp.exp(jnp.minimum(logw, 0.0)), 0.0)
     y_intra = jax.lax.dot_general(                # (c, P)
         s_mat, xdt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     # Inter-chunk: y_i += exp(cum_i) * C_i @ h0^T ; h0 is (P, N).
     h0 = h_ref[...]
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum) * jax.lax.dot_general(
         cmat, h0, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
     # State update: h = exp(cum_last) h0 + (xdt ⊙ exp(cum_last - cum))ᵀ B.
-    wlast = jnp.exp(cum[-1] - cum)[:, None]       # (c, 1)
-    h_new = jnp.exp(cum[-1]) * h0 + jax.lax.dot_general(
+    # (1, 1) = cum[-1]; Mosaic cannot broadcast a slice of cum's last row.
+    last = jnp.sum(la, axis=0, keepdims=True)
+    wlast = jnp.exp(last - cum)                   # (c, 1)
+    h_new = jnp.exp(last) * h0 + jax.lax.dot_general(
         xdt * wlast, bmat, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     h_ref[...] = h_new
@@ -62,7 +73,7 @@ def _ssd_kernel(xdt_ref, la_ref, b_ref, c_ref, y_ref, state_ref, h_ref, *, chunk
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(
     xdt: jax.Array,   # (BH, S, P) — dt-premultiplied input
-    la: jax.Array,    # (BH, S)    — log decay dt*A (<= 0)
+    la: jax.Array,    # (BH, S, 1) — log decay dt*A (<= 0)
     b: jax.Array,     # (BH, S, N)
     c: jax.Array,     # (BH, S, N)
     *,
@@ -87,7 +98,7 @@ def ssd_scan(
         grid=(bh, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
+            pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
         ],

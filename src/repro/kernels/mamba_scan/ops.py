@@ -179,7 +179,7 @@ def ssd(
         bb = jnp.repeat(b, rep, axis=2) if rep > 1 else b     # (B,S,H,N)
         cc = jnp.repeat(c, rep, axis=2) if rep > 1 else c
         xdt = (x * dt[..., None]).transpose(0, 2, 1, 3).reshape(bsz * h, s, p)
-        la = (dt * a[None, None, :]).transpose(0, 2, 1).reshape(bsz * h, s)
+        la = (dt * a[None, None, :]).transpose(0, 2, 1).reshape(bsz * h, s, 1)
         bf = bb.transpose(0, 2, 1, 3).reshape(bsz * h, s, n)
         cf = cc.transpose(0, 2, 1, 3).reshape(bsz * h, s, n)
         if interpret is None:
@@ -189,7 +189,7 @@ def ssd(
         pad = (-s) % chunk
         if pad:
             xdt = jnp.pad(xdt, ((0, 0), (0, pad), (0, 0)))
-            la = jnp.pad(la, ((0, 0), (0, pad)))
+            la = jnp.pad(la, ((0, 0), (0, pad), (0, 0)))
             bf = jnp.pad(bf, ((0, 0), (0, pad), (0, 0)))
             cf = jnp.pad(cf, ((0, 0), (0, pad), (0, 0)))
         y, state = _ssd_kernel_call(

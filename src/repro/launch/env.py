@@ -35,8 +35,6 @@ TUNED_ENV = {
     "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD": "60000000000",
     # Silence TF's C++ dataset/stream_executor chatter.
     "TF_CPP_MIN_LOG_LEVEL": "4",
-    # Persistent XLA compile cache (consumed by kernels/autotune.py).
-    "REPRO_JAX_CACHE": "1",
 }
 
 #: Where distros put tcmalloc (first hit wins; absent -> no preload).
@@ -83,9 +81,7 @@ def apply(n_host_devices: int | None = None) -> dict[str, str]:
     applied = tuned_env(n_host_devices)
     applied.pop("LD_PRELOAD", None)
     os.environ.update(applied)
-    cache = enable_compilation_cache()
-    if cache:
-        applied["REPRO_JAX_CACHE_DIR"] = cache
+    enable_compilation_cache()
     return applied
 
 

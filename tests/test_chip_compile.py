@@ -1,0 +1,115 @@
+"""Compiles for a described TPU v5e chip, at real widths, with none attached.
+
+The TPU compiler is installed beside the CPU backend, so the kernels of the
+main path and one whole Qwen2-1.5B decode step are compiled here for one chip
+of a described ``v5e:2x2`` topology.  This catches what interpret mode
+cannot: block shapes off the (8, 128) tiling, primitives Mosaic cannot lower,
+and programs that do not fit the chip.  Nothing runs, so nothing here is a
+time or a result.
+
+The topology is described inside a fixture, never at import: only one process
+may load the TPU library, and every test worker imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.flash_attention.ops import mha
+from repro.kernels.mamba_scan.ops import ssd
+from repro.kernels.matmul.ops import matmul
+from repro.kernels.prefill.ops import prefill_attention
+from repro.models.model import Model
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # Else the TPU library writes its logs under the temporary directory.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_MIN_LOG_LEVEL", "3")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel_cases():
+    # Block sizes are the ops' TPU defaults: on the CPU the autotune registry
+    # would otherwise hand them its CPU entries.
+    def prefill(q, k, v):
+        return prefill_attention(q, k, v, cache_dtype=BF16, block_q=256,
+                                 block_k=256, use_pallas=True, interpret=False)
+
+    def flash(q, k, v):
+        return mha(q, k, v, causal=True, block_q=512, block_k=512,
+                   use_pallas=True, interpret=False)
+
+    def mm(x, y):
+        return matmul(x, y, block_m=256, block_n=256, block_k=512,
+                      use_pallas=True, interpret=False)
+
+    def ssd_scan(x, dt, a, b, c):
+        return ssd(x, dt, a, b, c, None, chunk=128, use_pallas=True,
+                   interpret=False)
+
+    # Qwen2-1.5B attention: 16 padded q-heads, 2 KV heads, head_dim 128;
+    # mamba2-2.7b SSD: 80 heads of 64, state 128, one group.
+    return {
+        "prefill_flash": (prefill, [((1, 512, 16, 128), BF16),
+                                    ((1, 512, 2, 128), BF16),
+                                    ((1, 512, 2, 128), BF16)]),
+        "flash_attention": (flash, [((1, 4096, 16, 128), BF16),
+                                    ((1, 4096, 2, 128), BF16),
+                                    ((1, 4096, 2, 128), BF16)]),
+        "matmul": (mm, [((4096, 4096), BF16), ((4096, 4096), BF16)]),
+        "ssd_scan": (ssd_scan, [((1, 1024, 80, 64), BF16),
+                                ((1, 1024, 80), BF16),
+                                ((80,), jnp.float32),
+                                ((1, 1024, 1, 128), BF16),
+                                ((1, 1024, 1, 128), BF16)]),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_cases()))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_cases()[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_1_5b_decode_step_compiles_for_v5e(one_chip):
+    model = Model(get_config("qwen2-1.5b"))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(model.abstract_params())
+    caches = on_chip(jax.eval_shape(lambda: model.init_cache(8, 2048)))
+    toks = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
+        params, caches, toks, pos).compile()
+    mem = compiled.memory_analysis()
+    # Weights and cache stay within one chip's 16 GB.
+    assert mem.argument_size_in_bytes < 16 * 2**30
