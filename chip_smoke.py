@@ -47,6 +47,7 @@ from repro.core.wallclock import WallclockBackend  # noqa: E402
 from repro.kernels.autotune import enable_compilation_cache  # noqa: E402
 from repro.kernels.prefill.ops import length_bucket  # noqa: E402
 from repro.models.model import Model  # noqa: E402
+from repro.obs import Tracer  # noqa: E402
 from repro.serve.engine import DecodeEngine, Request  # noqa: E402
 
 ARCH = "qwen2-1.5b"
@@ -119,52 +120,27 @@ def make_requests(seed: int, n: int, prompt_len: tuple[int, int],
     ]
 
 
-class TimedEngine(DecodeEngine):
-    """A ``DecodeEngine`` that times each step and prefill to
-    ``block_until_ready`` and records which requests each step finished."""
-
-    def __init__(self, *args, **kwargs):
-        super().__init__(*args, **kwargs)
-        self.step_s: list[float] = []
-        self.prefill_s: list[tuple[int, float]] = []
-        self.finished: list[int] = []
-
-    def step(self):
-        steps, t0 = self.steps, time.perf_counter()
-        done = super().step()
-        if self.steps != steps:
-            jax.block_until_ready(self.caches)
-            self.step_s.append(time.perf_counter() - t0)
-        self.finished.extend(r.rid for r in done)
-        return done
-
-    def prefill(self, req):
-        t0 = time.perf_counter()
-        h = super().prefill(req)
-        jax.block_until_ready(h.caches)
-        self.prefill_s.append((h.bucket, time.perf_counter() - t0))
-        return h
-
-
 def serve(fleet, model: Model, params, requests: list[Request],
-          max_seq: int) -> tuple[object, dict[str, TimedEngine]]:
-    """Phases 4 and 5: serve ``requests`` on ``fleet`` through
+          max_seq: int) -> tuple[object, dict[str, DecodeEngine], Tracer]:
+    """Phases 4 and 5: serve ``requests`` on ``fleet`` through a traced
     ``Cluster.serve``; every request must finish exactly once with all of
-    its tokens."""
-    engines: dict[str, TimedEngine] = {}
+    its tokens.  Step and prefill times are the engines' ``engine.step``
+    and ``engine.prefill`` spans."""
+    engines: dict[str, DecodeEngine] = {}
 
     def factory(spec):
-        eng = TimedEngine(model, params, max_batch=spec.concurrency,
-                          max_seq=max_seq, name=spec.name)
+        eng = DecodeEngine(model, params, max_batch=spec.concurrency,
+                           max_seq=max_seq, name=spec.name)
         engines[spec.name] = eng
         return eng
 
+    tracer = Tracer()
     t0 = time.perf_counter()
-    rep = Cluster(fleet, backend="wallclock").serve(
+    rep = Cluster(fleet, backend="wallclock", trace=tracer).serve(
         ServeJob(requests, engine_factory=factory, max_seq=max_seq))
     wall = time.perf_counter() - t0
     done = collections.Counter(
-        rid for e in engines.values() for rid in e.finished)
+        e.data["rid"] for e in tracer.events if e.kind == "request_done")
     bad = [r.rid for r in requests
            if done[r.rid] != 1 or not r.done
            or len(r.out_tokens) != r.max_new_tokens]
@@ -174,20 +150,24 @@ def serve(fleet, model: Model, params, requests: list[Request],
         f"exactly once, tokens={int(rep.work_done)} wall_s={wall:.3f} "
         f"backend={rep.backend} mode={rep.metrics.get('mode', 'waves')}")
     for name, e in engines.items():
-        if e.step_s:
-            steady = e.step_s[1:]
-            log(f"  engine {name}: slots={e.max_batch} steps={len(e.step_s)} "
-                f"first_step_s={e.step_s[0]:.3f} steady_step_s="
+        step_s = [s.seconds for s in tracer.spans
+                  if s.name == "engine.step" and s.worker == name
+                  and s.attrs["active"]]
+        if step_s:
+            steady = step_s[1:]
+            log(f"  engine {name}: slots={e.max_batch} steps={len(step_s)} "
+                f"first_step_s={step_s[0]:.3f} steady_step_s="
                 + (f"{statistics.median(steady):.6f} (median of "
                    f"{len(steady)})" if steady else "none"))
         by_bucket = collections.defaultdict(list)
-        for bucket, s in e.prefill_s:
-            by_bucket[bucket].append(s)
+        for s in tracer.spans:
+            if s.name == "engine.prefill" and s.worker == name:
+                by_bucket[s.attrs["bucket"]].append(s.seconds)
         for bucket, ts in sorted(by_bucket.items()):
             log(f"  engine {name}: prefill bucket={bucket} calls={len(ts)} "
                 f"first_s={ts[0]:.3f} steady_s="
                 + (f"{statistics.median(ts[1:]):.6f}" if ts[1:] else "none"))
-    return rep, engines
+    return rep, engines, tracer
 
 
 def prefill_program(model: Model, params, prompt: list[int], max_seq: int):
@@ -247,7 +227,7 @@ def four_chips(cfg, seed: int) -> None:
                     f"expected four distinct devices, got {stats.device_of}")
     model, params = build_model(cfg, seed)
     reqs = make_requests(seed, 4, (8, 16), 4, cfg.vocab_size)
-    _, engines = serve(MIXED_FLEET, model, params, reqs, max_seq=64)
+    _, engines, _ = serve(MIXED_FLEET, model, params, reqs, max_seq=64)
     for name, e in engines.items():
         where = {str(d) for x in jax.tree.leaves(e.caches) for d in x.devices()}
         log(f"  engine {name}: cache on {sorted(where)}")

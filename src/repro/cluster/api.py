@@ -43,7 +43,8 @@ Construction knobs (all fleet-wide):
                   gossip-staleness and dispatch-throughput stats,
   ``trace``       an ``obs.Tracer`` (or ``True`` for a default one) records
                   grain-lifecycle/coordinator/gossip/serve events across
-                  every run this Cluster executes; ``tracer.export(path)``
+                  every run this Cluster executes, and the serving path's
+                  spans (``tracer.spans``); ``tracer.export(path)``
                   writes Perfetto or JSONL, and ``RunReport.telemetry``
                   carries the metrics rollup.  None (default) keeps the
                   untraced path bitwise-identical and overhead-free.

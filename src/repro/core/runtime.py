@@ -1354,7 +1354,12 @@ class AsyncRuntime:
                 del ticks[w]
                 self.authority.count_event(w, "tick", ctx)
                 worker = self.workers[w]
-                if sim_exec:
+                if tracer is not None:
+                    with tracer.span("runtime.tick", worker=w):
+                        finished = (
+                            executor.tick(worker, now) if sim_exec
+                            else backend.timed_tick(executor, worker, now))
+                elif sim_exec:
                     finished = executor.tick(worker, now)
                 else:
                     finished = backend.timed_tick(executor, worker, now)

@@ -22,8 +22,17 @@ seconds under the sim backend, measured seconds under wallclock — so traces
 from both backends read identically.  The wall timestamp rides along in
 ``args.wall_s``.
 
+Spans (``Tracer.spans``) go in a second process, ``repro spans (wall
+clock)``, on the host's clock in microseconds since the tracer's creation:
+one thread per worker plus ``fleet`` for spans with no worker.  A ``span``
+renders as a duration slice (``ph:"X"``) on its worker's thread, nested as
+it was opened; a request's ``open``/``close`` span, which overlaps the other
+requests' waits, renders as an async slice pair (``ph:"b"``/``"e"``, id =
+its rid).  Spans still open are left out.
+
 JSONL (``*.jsonl`` paths): one event object per line, all fields flat —
-the grep/jq-friendly stream for long open-loop runs.
+the grep/jq-friendly stream for long open-loop runs — then one line per
+closed span (``"span"``: its name).
 """
 
 from __future__ import annotations
@@ -31,11 +40,12 @@ from __future__ import annotations
 import json
 from typing import Iterable
 
-from .trace import TraceEvent
+from .trace import Span, TraceEvent
 
 __all__ = ["to_perfetto", "write_trace", "write_jsonl"]
 
 _PID = 1
+_SPAN_PID = 2
 _FLOW_KINDS = ("migrate", "steal", "cross_steal")
 
 
@@ -43,8 +53,10 @@ def _us(t_s: float) -> float:
     return round(t_s * 1e6, 3)
 
 
-def to_perfetto(events: Iterable[TraceEvent]) -> dict:
-    """Build the ``{"traceEvents": [...]}`` document (see module doc)."""
+def to_perfetto(events: Iterable[TraceEvent], spans: Iterable[Span] = (),
+                origin_ns: int = 0) -> dict:
+    """Build the ``{"traceEvents": [...]}`` document (see module doc);
+    span times count from ``origin_ns`` on ``time.perf_counter_ns``."""
     events = list(events)
     workers = sorted({e.worker for e in events if e.worker is not None})
     shards = sorted({
@@ -121,10 +133,39 @@ def to_perfetto(events: Iterable[TraceEvent]) -> dict:
         else:
             out.append({**base, "ph": "i", "s": "t", "name": e.kind,
                         "args": args})
+    out += _span_records([s for s in spans if s.t1_ns is not None], origin_ns)
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
-def write_jsonl(events: Iterable[TraceEvent], path: str) -> int:
+def _span_records(spans: list[Span], origin_ns: int) -> list[dict]:
+    if not spans:
+        return []
+    tids = {"fleet": 0}
+    for w in sorted({s.worker for s in spans if s.worker is not None}):
+        tids[w] = len(tids)
+    out = [{"ph": "M", "name": "process_name", "pid": _SPAN_PID, "tid": 0,
+            "ts": 0, "args": {"name": "repro spans (wall clock)"}}]
+    out += [{"ph": "M", "name": "thread_name", "pid": _SPAN_PID, "tid": t,
+             "ts": 0, "args": {"name": w}} for w, t in tids.items()]
+    for s in spans:
+        rec = {"pid": _SPAN_PID, "tid": tids[s.worker or "fleet"],
+               "name": s.name, "cat": "span",
+               "args": {**s.attrs, "rid": s.rid, "id": s.id,
+                        "parent": s.parent}}
+        ts = (s.t0_ns - origin_ns) / 1e3
+        if s.keyed:
+            # A request's wait: async, since the requests' waits overlap.
+            rec.update(cat="request", id=s.rid)
+            out.append({**rec, "ph": "b", "ts": ts})
+            out.append({**rec, "ph": "e", "ts": (s.t1_ns - origin_ns) / 1e3})
+        else:
+            out.append({**rec, "ph": "X", "ts": ts,
+                        "dur": (s.t1_ns - s.t0_ns) / 1e3})
+    return out
+
+
+def write_jsonl(events: Iterable[TraceEvent], path: str,
+                spans: Iterable[Span] = ()) -> int:
     n = 0
     with open(path, "w") as f:
         for e in events:
@@ -133,15 +174,27 @@ def write_jsonl(events: Iterable[TraceEvent], path: str) -> int:
                 "worker": e.worker, "grain": e.grain, **e.data,
             }) + "\n")
             n += 1
+        for s in spans:
+            if s.t1_ns is None:
+                continue
+            f.write(json.dumps({
+                "span": s.name, "id": s.id, "parent": s.parent,
+                "worker": s.worker, "rid": s.rid, "t0_ns": s.t0_ns,
+                "t1_ns": s.t1_ns, **s.attrs,
+            }) + "\n")
+            n += 1
     return n
 
 
-def write_trace(events: Iterable[TraceEvent], path: str) -> int:
+def write_trace(events: Iterable[TraceEvent], path: str,
+                spans: Iterable[Span] = (), origin_ns: int = 0) -> int:
     """Format by extension: ``.jsonl`` -> JSONL stream, anything else ->
-    Perfetto ``trace_event`` JSON.  Returns events written."""
+    Perfetto ``trace_event`` JSON.  Returns events and closed spans
+    written."""
     events = list(events)
+    spans = [s for s in spans if s.t1_ns is not None]
     if path.endswith(".jsonl"):
-        return write_jsonl(events, path)
+        return write_jsonl(events, path, spans)
     with open(path, "w") as f:
-        json.dump(to_perfetto(events), f)
-    return len(events)
+        json.dump(to_perfetto(events, spans, origin_ns), f)
+    return len(events) + len(spans)

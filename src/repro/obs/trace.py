@@ -37,6 +37,25 @@ whose ``snapshot()`` becomes ``RunReport.telemetry``.  With
 ``metrics_interval_s`` set, the tracer prints a one-line stat summary every
 time the logical clock crosses the next interval boundary — the live-run
 heartbeat for long open-loop streams.
+
+Beside its events a tracer records *spans*: named stretches of host time on
+``time.perf_counter_ns``, each with its parent span, worker, request id
+(``rid``) and a free-form attrs dict.  Two forms:
+
+  ``with tracer.span(name, worker=..., rid=..., **attrs) as sp:``
+      a stretch of one call; spans opened inside it are its children, and a
+      child without a worker inherits its parent's.  ``sp.set(**attrs)``
+      adds counters before it closes.
+  ``tracer.open(name, rid)`` ... ``tracer.close(name, rid, worker=...)``
+      a request's wait that begins in one call and ends in another, keyed by
+      ``(name, rid)``; closing a span that is not open does nothing.
+
+While a span is open it is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, so a profiler trace taken meanwhile holds every span on
+its host plane, on the same clock as the device's operations.  Spans stay in
+memory (``Tracer.spans``) until ``export`` writes them.  Span sites follow
+the emit-site contract: with no tracer they take ``NO_SPAN``, a shared
+do-nothing stand-in, so no span is built and no annotation entered.
 """
 
 from __future__ import annotations
@@ -47,7 +66,8 @@ from typing import Any, Callable
 
 from .metrics import MetricsRegistry
 
-__all__ = ["TraceEvent", "Tracer", "EVENT_KINDS"]
+__all__ = ["EVENT_KINDS", "EventTracer", "NO_SPAN", "Span", "TraceEvent",
+           "Tracer"]
 
 #: The closed event vocabulary (exporters render anything, but tests assert
 #: emitting layers stay inside it).
@@ -75,8 +95,91 @@ class TraceEvent:
     data: dict[str, Any]       # kind-specific payload
 
 
+#: Prefix of the profiler annotation each span enters: ``repro.<name>``.
+SPAN_PREFIX = "repro."
+
+
+@dataclasses.dataclass(slots=True)
+class Span:
+    name: str
+    id: int                    # unique within its tracer
+    parent: int | None         # id of the enclosing span (None: top level)
+    worker: str | None         # track owner
+    rid: int | None            # request id, shared by all spans of a request
+    t0_ns: int                 # time.perf_counter_ns at open
+    t1_ns: int | None          # ... at close (None while open)
+    attrs: dict[str, Any]
+    keyed: bool = False        # opened by ``open``, closed by ``close``
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1_ns - self.t0_ns) * 1e-9
+
+
+def _annotation(name: str):
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(SPAN_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
+class _NoSpan:
+    """What a span site uses when there is no tracer: ``NO_SPAN(name, ...)``
+    returns itself, entering and ``set`` do nothing."""
+
+    __slots__ = ()
+
+    def __call__(self, name: str, **attrs: Any) -> "_NoSpan":
+        return self
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **attrs: Any) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Scope:
+    """One ``Tracer.span`` while it is open."""
+
+    __slots__ = ("_tracer", "_span", "_ann")
+
+    def __init__(self, tracer: "Tracer", span: Span):
+        self._tracer = tracer
+        self._span = span
+
+    def __enter__(self) -> "_Scope":
+        tr, sp = self._tracer, self._span
+        stack = tr._stack
+        if stack:
+            sp.parent = stack[-1].id
+            if sp.worker is None:
+                sp.worker = stack[-1].worker
+        self._ann = _annotation(sp.name)
+        sp.t0_ns = time.perf_counter_ns()
+        tr.spans.append(sp)
+        stack.append(sp)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._span.t1_ns = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._tracer._stack.pop()
+
+    def set(self, **attrs: Any) -> None:
+        self._span.attrs.update(attrs)
+
+
 class Tracer:
-    """Collects ``TraceEvent``s and rolls them into a ``MetricsRegistry``.
+    """Collects ``TraceEvent``s and rolls them into a ``MetricsRegistry``;
+    records ``Span``s (module docstring).
 
     Parameters:
       metrics_interval_s  print a one-line summary every S logical seconds
@@ -100,6 +203,10 @@ class Tracer:
         self._next_report_s = (
             self.metrics_interval_s if self.metrics_interval_s else None
         )
+        self.spans: list[Span] = []
+        self._stack: list[Span] = []        # open ``span`` scopes, innermost last
+        self._open: dict[tuple[str, int], tuple[Span, Any]] = {}
+        self._next_span = 0
 
     # -- wiring ---------------------------------------------------------------
     def set_clock(self, clock: Callable[[], float]) -> None:
@@ -143,6 +250,40 @@ class Tracer:
             ) * interval
             self.log_fn(self.summary_line(t))
 
+    # -- spans (only reached when tracing is ON) --------------------------------
+    def _new_span(self, name: str, worker: str | None, rid: int | None,
+                  attrs: dict[str, Any]) -> Span:
+        self._next_span += 1
+        return Span(name, self._next_span, None, worker, rid, 0, None, attrs)
+
+    def span(self, name: str, *, worker: str | None = None,
+             rid: int | None = None, **attrs: Any) -> _Scope:
+        """A span over the ``with`` block it opens (module docstring)."""
+        return _Scope(self, self._new_span(name, worker, rid, attrs))
+
+    def open(self, name: str, rid: int, **attrs: Any) -> None:
+        """Begin request ``rid``'s span ``name``; ``close`` ends it."""
+        sp = self._new_span(name, attrs.pop("worker", None), rid, attrs)
+        sp.keyed = True
+        ann = _annotation(name)
+        sp.t0_ns = time.perf_counter_ns()
+        self.spans.append(sp)
+        self._open[(name, rid)] = (sp, ann)
+
+    def close(self, name: str, rid: int, *, worker: str | None = None,
+              **attrs: Any) -> None:
+        """End request ``rid``'s open span ``name``, if any; ``worker``
+        names where the wait ended."""
+        entry = self._open.pop((name, rid), None)
+        if entry is None:
+            return
+        sp, ann = entry
+        sp.t1_ns = time.perf_counter_ns()
+        ann.__exit__(None, None, None)
+        if worker is not None:
+            sp.worker = worker
+        sp.attrs.update(attrs)
+
     # -- reporting ------------------------------------------------------------
     def summary_line(self, t_s: float | None = None) -> str:
         """One-line live stats: event totals for the kinds that tell the
@@ -166,8 +307,24 @@ class Tracer:
         return snap
 
     def export(self, path: str) -> int:
-        """Write the collected events to ``path``: Perfetto/Chrome
-        ``trace_event`` JSON, or compact JSONL when the path ends in
-        ``.jsonl``.  Returns the number of events written."""
+        """Write the collected events and closed spans to ``path``:
+        Perfetto/Chrome ``trace_event`` JSON, or compact JSONL when the path
+        ends in ``.jsonl``.  Returns the number of records written."""
         from .export import write_trace
-        return write_trace(self.events, path)
+        return write_trace(self.events, path, spans=self.spans,
+                           origin_ns=int(self._origin * 1e9))
+
+
+class EventTracer(Tracer):
+    """A ``Tracer`` that keeps events and no spans: the carrier the serving
+    plane attaches for a stream's lifecycle events when the caller traces
+    nothing, so an untraced stream builds no span."""
+
+    def span(self, name: str, **attrs: Any) -> _NoSpan:
+        return NO_SPAN
+
+    def open(self, name: str, rid: int, **attrs: Any) -> None:
+        pass
+
+    def close(self, name: str, rid: int, **attrs: Any) -> None:
+        pass

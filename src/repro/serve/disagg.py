@@ -136,7 +136,6 @@ class DisaggExecutor(GrainExecutor):
     pooled = True
     uniform_cost = None
     step_clock = None   # wall-clock backend seam, as on EngineExecutor
-    tracer = None       # serve-plane tracing seam, as on EngineExecutor
 
     def __init__(
         self,
@@ -149,7 +148,9 @@ class DisaggExecutor(GrainExecutor):
         prefill_chunk: int = 16,
         handoff_latency_s: float = 0.005,
         handoff_per_token_s: float = 0.0,
+        tracer=None,
     ):
+        self.tracer = tracer   # serve-plane tracing, as on EngineExecutor
         self.engines = dict(engines)
         self.engine_factory = engine_factory
         self.requests = list(requests)
@@ -214,6 +215,7 @@ class DisaggExecutor(GrainExecutor):
                 f"engine {name!r} max_seq {eng.max_seq} cannot hold this "
                 f"bundle's largest request ({self._max_positions} positions)"
             )
+        eng.tracer = self.tracer
 
     def engine_for(self, worker):
         eng = self.engines.get(worker.name)
@@ -286,6 +288,9 @@ class DisaggExecutor(GrainExecutor):
             self._pf[grain] = 0
             self._pf_lane.setdefault(worker.name, []).append(grain)
             self.prefill_begin_s[grain] = now_s
+            if self.tracer is not None:
+                self.tracer.close("request.queue", self.requests[grain].rid,
+                                  worker=worker.name)
             return
         i = grain - self.n
         self.insert_s[i] = now_s
@@ -319,6 +324,8 @@ class DisaggExecutor(GrainExecutor):
                 if self.tracer is not None:
                     self.tracer.emit("first_token", t_s=now_s, worker=name,
                                      grain=g)
+                    # Ends where a decode engine inserts it.
+                    self.tracer.open("request.handoff", r.rid)
                 done.append((g, h))
             return done
         finished = self.engine_for(worker).step()

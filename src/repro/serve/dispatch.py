@@ -158,9 +158,9 @@ class HomogenizedDispatcher:
         if roles:
             executor = DisaggExecutor(engines, requests, roles,
                                       engine_factory=engine_factory,
-                                      on_finish=on_finish)
+                                      on_finish=on_finish,
+                                      tracer=self.runtime.tracer)
             executor.step_clock = self._step_clock
-            executor.tracer = self.runtime.tracer
             run = self.runtime.run(
                 2 * len(requests),
                 executor=executor,
@@ -174,9 +174,9 @@ class HomogenizedDispatcher:
             return self._result(run), run, executor
         executor = EngineExecutor(engines, requests,
                                   engine_factory=engine_factory,
-                                  on_finish=on_finish)
+                                  on_finish=on_finish,
+                                  tracer=self.runtime.tracer)
         executor.step_clock = self._step_clock
-        executor.tracer = self.runtime.tracer
         run = self.runtime.run(
             len(requests),
             executor=executor,
@@ -231,7 +231,8 @@ class HomogenizedDispatcher:
 
         if batched:
             executor = EngineExecutor(engines, requests,
-                                      engine_factory=engine_factory)
+                                      engine_factory=engine_factory,
+                                      tracer=self.runtime.tracer)
             executor.step_clock = self._step_clock
             run = self.runtime.run(
                 len(requests),
@@ -248,6 +249,7 @@ class HomogenizedDispatcher:
                 if engine_factory is None:
                     raise KeyError(f"replica {replica.name!r} has no engine")
                 eng = engines[replica.name] = engine_factory(replica)
+            eng.tracer = self.runtime.tracer
             return eng
 
         def execute(replica, i):

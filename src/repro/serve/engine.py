@@ -17,6 +17,16 @@ engine, including a different replica (prefill/decode disaggregation).
 
 The engine reports throughput heartbeats which the homogenized dispatcher
 (dispatch.py) consumes for cross-replica scope-length allotment.
+
+With ``tracer`` set (an ``obs.Tracer``; the executor running the engine sets
+it), each call is a span: ``engine.step`` with its phases ``.prep`` (slot
+admission and the input arrays), ``.device`` (the decode program, waited
+for), ``.fetch`` (the logits pulled to the host) and ``.sample`` (the
+per-slot argmax and bookkeeping), and counters ``active``, ``feeding``
+(active slots that consumed a prompt token and sampled nothing),
+``sampled`` and ``max_batch``; ``engine.prefill`` with ``.device`` and
+``.fetch``; ``engine.insert``.  Admission closes a request's
+``request.queue`` span and ``insert`` its ``request.handoff``.
 """
 
 from __future__ import annotations
@@ -30,6 +40,7 @@ import numpy as np
 from ..core.performance import PerfReport
 from ..kernels.prefill.ops import length_bucket
 from ..models.model import Model
+from ..obs import NO_SPAN
 
 
 @dataclasses.dataclass
@@ -96,6 +107,7 @@ class DecodeEngine:
         self.tokens_out = 0
         self.prompt_fed = 0      # prompt tokens consumed (feed or prefill)
         self.handoffs_in = 0     # KVHandoffs inserted into this engine
+        self.tracer = None       # obs.Tracer, set by the executor running it
         self._hb_steps = 0
         self._hb_tokens = 0
         self._hb_fed = 0
@@ -108,11 +120,15 @@ class DecodeEngine:
         self.queue.append(req)
 
     def _admit(self) -> None:
+        tracer = self.tracer
         for slot in self.slots:
             if slot.req is None and self.queue:
                 slot.req = self.queue.pop(0)
                 slot.pos = 0
                 slot.fed = 0
+                if tracer is not None:
+                    tracer.close("request.queue", slot.req.rid,
+                                 worker=self.name)
 
     @property
     def active(self) -> int:
@@ -156,26 +172,35 @@ class DecodeEngine:
         if L + req.max_new_tokens > self.max_seq:
             raise ValueError("request exceeds engine max_seq")
         bucket = length_bucket(L, self.max_seq)
-        toks = np.zeros((1, bucket), np.int64)
-        toks[0, :L] = req.prompt
-        fn = self._prefills.get(bucket)
-        if fn is None:
-            model = self.model
+        tracer = self.tracer
+        span = NO_SPAN if tracer is None else tracer.span
+        with span("engine.prefill", worker=self.name, rid=req.rid, length=L,
+                  bucket=bucket):
+            toks = np.zeros((1, bucket), np.int64)
+            toks[0, :L] = req.prompt
+            fn = self._prefills.get(bucket)
+            if fn is None:
+                model = self.model
 
-            def run(params, toks, last_pos):
-                return model.prefill(params, {"tokens": toks},
-                                     last_pos=last_pos)
+                def run(params, toks, last_pos):
+                    return model.prefill(params, {"tokens": toks},
+                                         last_pos=last_pos)
 
-            fn = jax.jit(run)
-            self._prefills[bucket] = fn
-        logits, caches = fn(
-            self.params, jnp.asarray(toks, jnp.int32), jnp.int32(L - 1)
-        )
-        lg = np.asarray(logits[0, 0, : self.model.cfg.vocab_size], np.float32)
-        first = (
-            int(lg.argmax()) if self.greedy
-            else int(self.rng.choice(self.model.cfg.vocab_size))
-        )
+                fn = jax.jit(run)
+                self._prefills[bucket] = fn
+            with span("engine.prefill.device"):
+                logits, caches = fn(
+                    self.params, jnp.asarray(toks, jnp.int32),
+                    jnp.int32(L - 1)
+                )
+                logits.block_until_ready()
+            with span("engine.prefill.fetch"):
+                lg = np.asarray(logits[0, 0, : self.model.cfg.vocab_size],
+                                np.float32)
+                first = (
+                    int(lg.argmax()) if self.greedy
+                    else int(self.rng.choice(self.model.cfg.vocab_size))
+                )
         self.prompt_fed += L
         self.tokens_out += 1
         return KVHandoff(req=req, pos=L, first_token=first, caches=caches,
@@ -191,6 +216,16 @@ class DecodeEngine:
         replica can re-insert the *same* handoff on the heir and decode a
         bitwise-identical continuation — the prefill is never recomputed and
         never double-counted."""
+        tracer = self.tracer
+        if tracer is None:
+            return self._insert(handoff)
+        tracer.close("request.handoff", handoff.req.rid, worker=self.name)
+        with tracer.span("engine.insert", worker=self.name,
+                         rid=handoff.req.rid, pos=handoff.pos,
+                         bucket=handoff.bucket):
+            return self._insert(handoff)
+
+    def _insert(self, handoff: KVHandoff) -> int:
         r = handoff.req
         if len(r.prompt) + r.max_new_tokens > self.max_seq:
             raise ValueError("request exceeds engine max_seq")
@@ -239,53 +274,70 @@ class DecodeEngine:
         Idle slots re-write position 0 of their own cache lane with a pad
         token — harmless (the lane is reinitialized on admission by writing
         from pos 0 upward, and validity masks bound attention at pos)."""
-        self._admit()
-        if self.active == 0:
-            return []
-        toks = np.zeros((self.max_batch, 1), np.int64)
-        pos = np.zeros((self.max_batch,), np.int64)
-        for i, slot in enumerate(self.slots):
-            r = slot.req
-            if r is None:
-                continue
-            pos[i] = slot.pos
-            if slot.fed < len(r.prompt):
-                toks[i, 0] = r.prompt[slot.fed]
-            else:
-                toks[i, 0] = r.out_tokens[-1]
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(toks, jnp.int32),
-            jnp.asarray(pos, jnp.int32),
-        )
-        self.steps += 1
-        finished = []
-        lg = np.asarray(logits[:, 0], np.float32)
-        for i, slot in enumerate(self.slots):
-            r = slot.req
-            if r is None:
-                continue
-            slot.pos += 1
-            if slot.fed < len(r.prompt):
-                slot.fed += 1
-                self.prompt_fed += 1
-                if slot.fed < len(r.prompt):
-                    continue  # still feeding prompt; no sample yet
-            nxt = (
-                int(lg[i, : self.model.cfg.vocab_size].argmax())
-                if self.greedy
-                else int(self.rng.choice(self.model.cfg.vocab_size))
-            )
-            r.out_tokens.append(nxt)
-            self.tokens_out += 1
-            if (
-                len(r.out_tokens) >= r.max_new_tokens
-                or (self.eos_id is not None and nxt == self.eos_id)
-                or slot.pos >= self.max_seq
-            ):
-                r.done = True
-                r.finish_step = self.steps
-                finished.append(r)
-                slot.req = None
+        tracer = self.tracer
+        span = NO_SPAN if tracer is None else tracer.span
+        with span("engine.step", worker=self.name) as step:
+            with span("engine.step.prep"):
+                self._admit()
+                active = self.active
+                if active:
+                    toks = np.zeros((self.max_batch, 1), np.int64)
+                    pos = np.zeros((self.max_batch,), np.int64)
+                    for i, slot in enumerate(self.slots):
+                        r = slot.req
+                        if r is None:
+                            continue
+                        pos[i] = slot.pos
+                        if slot.fed < len(r.prompt):
+                            toks[i, 0] = r.prompt[slot.fed]
+                        else:
+                            toks[i, 0] = r.out_tokens[-1]
+            if active == 0:
+                step.set(active=0, feeding=0, sampled=0,
+                         max_batch=self.max_batch)
+                return []
+            with span("engine.step.device"):
+                logits, self.caches = self._decode(
+                    self.params, self.caches, jnp.asarray(toks, jnp.int32),
+                    jnp.asarray(pos, jnp.int32),
+                )
+                logits.block_until_ready()
+            self.steps += 1
+            with span("engine.step.fetch") as fetch:
+                lg = np.asarray(logits[:, 0], np.float32)
+                fetch.set(bytes=lg.nbytes)
+            finished = []
+            sampled = 0
+            with span("engine.step.sample"):
+                for i, slot in enumerate(self.slots):
+                    r = slot.req
+                    if r is None:
+                        continue
+                    slot.pos += 1
+                    if slot.fed < len(r.prompt):
+                        slot.fed += 1
+                        self.prompt_fed += 1
+                        if slot.fed < len(r.prompt):
+                            continue  # still feeding prompt; no sample yet
+                    nxt = (
+                        int(lg[i, : self.model.cfg.vocab_size].argmax())
+                        if self.greedy
+                        else int(self.rng.choice(self.model.cfg.vocab_size))
+                    )
+                    r.out_tokens.append(nxt)
+                    self.tokens_out += 1
+                    sampled += 1
+                    if (
+                        len(r.out_tokens) >= r.max_new_tokens
+                        or (self.eos_id is not None and nxt == self.eos_id)
+                        or slot.pos >= self.max_seq
+                    ):
+                        r.done = True
+                        r.finish_step = self.steps
+                        finished.append(r)
+                        slot.req = None
+            step.set(active=active, feeding=active - sampled, sampled=sampled,
+                     max_batch=self.max_batch)
         return finished
 
     def run_until_drained(self, max_steps: int = 10_000) -> list[Request]:

@@ -60,13 +60,14 @@ class EngineExecutor(GrainExecutor):
     # heartbeats report *measured* tokens/sec instead of the modeled
     # ``1 / perf`` profile.  None keeps the modeled clock.
     step_clock = None
-    # Serve-plane tracing (obs.Tracer), set by the dispatcher: first_token /
-    # ttft_drop / request_done events are *the* carrier for per-request
-    # latency — serve_stream folds them back into RequestTraces.
-    tracer = None
-
     def __init__(self, engines: Mapping[str, object], requests: Sequence,
-                 engine_factory=None, on_finish=None):
+                 engine_factory=None, on_finish=None, tracer=None):
+        # Serve-plane tracing (obs.Tracer), passed by the dispatcher:
+        # first_token / ttft_drop / request_done events are *the* carrier
+        # for per-request latency — serve_stream folds them back into
+        # RequestTraces.  Every engine this executor runs traces its spans
+        # into the same tracer.
+        self.tracer = tracer
         self.engines = dict(engines)
         self.engine_factory = engine_factory
         self.requests = list(requests)
@@ -120,6 +121,7 @@ class EngineExecutor(GrainExecutor):
                 f"engine {name!r} max_seq {eng.max_seq} cannot hold this "
                 f"bundle's largest request ({self._max_positions} positions)"
             )
+        eng.tracer = self.tracer
 
     def engine_for(self, worker):
         """The worker's engine, lazily built for mid-bundle joiners."""

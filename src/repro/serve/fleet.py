@@ -14,6 +14,12 @@ tracker state persists across waves, so wave k+1's allotment reflects what
 wave k actually observed.  Timeline events passed to ``serve`` are relative
 to its start; events landing past a wave's end carry over to the next wave
 (the runtime's pending-event semantics).
+
+With a tracer on the dispatcher's runtime, each wave is a ``serve.wave``
+span, and each request's wait before it reaches an engine is two request
+spans: ``request.backlog`` while it waits for its wave, ``request.queue``
+from its wave's dispatch (or, in an open-loop stream, the stream's start)
+until an engine admits it to a slot or its prefill begins.
 """
 
 from __future__ import annotations
@@ -26,7 +32,7 @@ from typing import Sequence
 from ..core.homogenization import scope_lengths
 from ..core.runtime import TimelineEvent
 from ..core.scheduler import GrainPlan
-from ..obs import Tracer
+from ..obs import NO_SPAN, EventTracer
 from .disagg import RoleStats, TTFTSplit, build_ttft_split
 from .dispatch import HomogenizedDispatcher, Replica
 
@@ -267,6 +273,11 @@ class FleetServer:
         if timeline_fn is not None and timeline:
             raise ValueError("pass either timeline or timeline_fn, not both")
         backlog = deque(requests)
+        tracer = self.dispatcher.runtime.tracer
+        span = NO_SPAN if tracer is None else tracer.span
+        if tracer is not None:
+            for r in backlog:
+                tracer.open("request.backlog", r.rid)
         bundles: list[BundleStats] = []
         first = True
         wave_idx = 0
@@ -282,16 +293,22 @@ class FleetServer:
                 wave_timeline = tuple(timeline_fn(wave_idx))
             else:
                 wave_timeline = timeline if first else ()
-            res, run = self.dispatcher.dispatch_to_engines(
-                {n: self.engines[n] for n in live if n in self.engines},
-                wave,
-                timeline=wave_timeline,
-                batched=batched,
-                engine_factory=(
-                    self._factory if self.engine_factory is not None else None
-                ),
-                initial_plan=self._wave_plan(len(wave)),
-            )
+            if tracer is not None:
+                for r in wave:
+                    tracer.close("request.backlog", r.rid)
+                    tracer.open("request.queue", r.rid)
+            with span("serve.wave", wave=wave_idx, n_requests=len(wave)):
+                res, run = self.dispatcher.dispatch_to_engines(
+                    {n: self.engines[n] for n in live if n in self.engines},
+                    wave,
+                    timeline=wave_timeline,
+                    batched=batched,
+                    engine_factory=(
+                        self._factory if self.engine_factory is not None
+                        else None
+                    ),
+                    initial_plan=self._wave_plan(len(wave)),
+                )
             first = False
             wave_idx += 1
             tokens = sum(len(r.out_tokens) for r in wave)
@@ -423,13 +440,16 @@ class FleetServer:
         # vocabulary: first_token / ttft_drop events from the executor and
         # complete events from the runtime fold back into RequestTraces
         # below.  With no caller-supplied tracer an ephemeral one carries the
-        # events for just this stream — same values the executor dict held,
-        # so LatencyStats output is byte-identical either way.
+        # events (and no spans) for just this stream — same values the
+        # executor dict held, so LatencyStats output is byte-identical
+        # either way.
         ephemeral = rt.tracer is None
         if ephemeral:
-            rt.tracer = Tracer()
+            rt.tracer = EventTracer()
         stream_tracer = rt.tracer
         ev_mark = len(stream_tracer.events)
+        for r in requests:
+            stream_tracer.open("request.queue", r.rid)
         joined: list[str] = []
         fired = [False] * len(scale_rules)
         ttfts: deque[float] = deque(

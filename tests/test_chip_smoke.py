@@ -28,13 +28,19 @@ def test_phases_at_tiny_size():
 
     for fleet in (smoke.MIXED_FLEET, smoke.DISAGG_FLEET):
         reqs = requests()
-        rep, engines = smoke.serve(fleet, model, params, reqs, max_seq=32)
+        rep, engines, tracer = smoke.serve(fleet, model, params, reqs,
+                                           max_seq=32)
         assert rep.work_done == sum(r.max_new_tokens for r in reqs)
-        assert sorted(rid for e in engines.values() for rid in e.finished) \
-            == [r.rid for r in reqs]
-        assert all(len(e.step_s) == e.steps for e in engines.values())
+        assert sorted(e.data["rid"] for e in tracer.events
+                      if e.kind == "request_done") == [r.rid for r in reqs]
+        for name, e in engines.items():
+            steps = [s for s in tracer.spans if s.name == "engine.step"
+                     and s.worker == name and s.attrs["active"]]
+            assert len(steps) == e.steps
+            assert all(s.seconds > 0 for s in steps)
     # The disaggregated fleet prefilled on the prefill replica only.
-    assert engines["p"].prefill_s and not engines["d"].prefill_s
+    prefills = {s.worker for s in tracer.spans if s.name == "engine.prefill"}
+    assert prefills == {"p"}
     prompt = max((r.prompt for r in reqs), key=len)
     diff = smoke.check_prefill_logits(model, params, prompt, max_seq=32)
     assert np.isfinite(diff) and diff < 1e-4    # float32 config

@@ -15,7 +15,10 @@ ends in exactly one complete or abort).  These tests pin both halves:
     tracer events are now the *only* carrier for TTFT/completion),
   - ``MetricsRegistry`` snapshot determinism + percentile arithmetic,
   - Perfetto ``trace_event`` structure: per-worker tracks, duration slices,
-    migration flow-event pairs; JSONL round-trip.
+    migration flow-event pairs; JSONL round-trip,
+  - spans: parents, inherited workers, request open/close pairs, their
+    profiler annotations, and their export (the serving path's spans are
+    tested through ``Cluster.serve`` in ``test_serve.py``).
 
 Offline constraint: deterministic seeded sweeps (no hypothesis).
 """
@@ -24,6 +27,7 @@ from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 from stub_engine import StubEngine, mk_requests
@@ -33,7 +37,9 @@ from repro.coord import CoordSpec, ShardedCoordinator
 from repro.core import (
     AsyncRuntime, PerformanceTracker, PerfReport, SimWorker, TimelineEvent,
 )
-from repro.obs import EVENT_KINDS, MetricsRegistry, Tracer, to_perfetto
+from repro.obs import (
+    EVENT_KINDS, NO_SPAN, EventTracer, MetricsRegistry, Tracer, to_perfetto,
+)
 from repro.serve import FleetServer, Replica
 
 DYADIC_COSTS = (0.25, 0.5, 1.0, 2.0, 4.0)
@@ -303,3 +309,99 @@ def test_perfetto_export_writes_loadable_json(tmp_path):
     doc = json.loads(path.read_text())
     assert doc["displayTimeUnit"] == "ms"
     assert len([e for e in doc["traceEvents"] if e["ph"] != "M"]) >= n
+
+
+# -------------------------------------------------------------------- spans
+def _spanned_tracer():
+    tracer = Tracer()
+    tracer.open("request.queue", 7)
+    with tracer.span("runtime.tick", worker="a") as tick:
+        with tracer.span("engine.step", n=1) as step:
+            step.set(active=2)
+        tick.set(done=True)
+    tracer.close("request.queue", 7, worker="a")
+    tracer.close("request.queue", 7)         # not open any more: nothing
+    tracer.close("request.handoff", 3)       # never opened: nothing
+    tracer.open("request.handoff", 8)        # left open
+    return tracer
+
+
+def test_span_records_parent_worker_rid_and_attrs():
+    tracer = _spanned_tracer()
+    queue, tick, step, handoff = tracer.spans
+    assert [s.name for s in tracer.spans] == [
+        "request.queue", "runtime.tick", "engine.step", "request.handoff"]
+    assert (tick.parent, step.parent, queue.parent) == (None, tick.id, None)
+    assert step.worker == "a"                # inherited from its parent
+    assert step.attrs == {"n": 1, "active": 2} and tick.attrs == {"done": True}
+    assert (queue.rid, queue.worker, queue.keyed) == (7, "a", True)
+    assert not tick.keyed
+    assert queue.t0_ns <= tick.t0_ns <= step.t0_ns <= step.t1_ns \
+        <= tick.t1_ns <= queue.t1_ns
+    assert handoff.t1_ns is None
+    assert tracer._stack == []
+
+
+def test_span_annotations_enter_the_profiler(monkeypatch):
+    entered, exited = [], []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            exited.append(self.name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    _spanned_tracer()
+    assert entered == ["repro.request.queue", "repro.runtime.tick",
+                       "repro.engine.step", "repro.request.handoff"]
+    assert exited == ["repro.engine.step", "repro.runtime.tick",
+                      "repro.request.queue"]
+
+
+def test_event_tracer_and_no_span_record_nothing():
+    tracer = EventTracer()
+    with tracer.span("engine.step", worker="a") as sp:
+        sp.set(active=1)
+    tracer.open("request.queue", 1)
+    tracer.close("request.queue", 1)
+    tracer.emit("arrive", t_s=0.0, grain=0)
+    assert tracer.spans == [] and len(tracer.events) == 1
+    with NO_SPAN("engine.step", worker="a") as sp:
+        sp.set(active=1)
+    assert NO_SPAN("x") is NO_SPAN
+
+
+def test_perfetto_export_writes_spans_on_the_wall_clock():
+    tracer = _spanned_tracer()
+    doc = to_perfetto(tracer.events, tracer.spans, origin_ns=0)
+    recs = [e for e in doc["traceEvents"] if e["pid"] == 2]
+    names = {e["args"]["name"] for e in recs if e["name"] == "thread_name"}
+    assert names == {"fleet", "a"}
+    slices = {e["name"]: e for e in recs if e["ph"] == "X"}
+    assert set(slices) == {"runtime.tick", "engine.step"}
+    step = tracer.spans[2]
+    assert slices["engine.step"]["ts"] == step.t0_ns / 1e3
+    assert slices["engine.step"]["dur"] == (step.t1_ns - step.t0_ns) / 1e3
+    assert slices["engine.step"]["args"]["active"] == 2
+    # The request's wait is an async pair; the span left open is not written.
+    pair = [e for e in recs if e["ph"] in ("b", "e")]
+    assert [(e["ph"], e["name"], e["id"]) for e in pair] == [
+        ("b", "request.queue", 7), ("e", "request.queue", 7)]
+
+
+def test_export_counts_events_and_closed_spans(tmp_path):
+    tracer = _spanned_tracer()
+    tracer.emit("arrive", t_s=0.0, grain=0)
+    n = tracer.export(str(tmp_path / "t.jsonl"))
+    lines = [json.loads(ln) for ln in (tmp_path / "t.jsonl").read_text().splitlines()]
+    assert n == len(lines) == 4
+    assert lines[0]["kind"] == "arrive"
+    assert [ln["span"] for ln in lines[1:]] == [
+        "request.queue", "runtime.tick", "engine.step"]
+    assert lines[3]["parent"] == lines[2]["id"] and lines[3]["active"] == 2
+    assert tracer.export(str(tmp_path / "t.json")) == 4

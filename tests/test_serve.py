@@ -1,12 +1,18 @@
-"""Serving tests: continuous-batching engine correctness + homogenized dispatch."""
+"""Serving tests: continuous-batching engine correctness + homogenized dispatch,
+and the serving path's spans."""
+
+import glob
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.cluster import Cluster, ServeJob
 from repro.core import TimelineEvent
 from repro.models import LayerSpec, Model, ModelConfig, MoEConfig
+from repro.obs import Tracer
+from repro.obs import trace as obs_trace
 from repro.serve import (
     DecodeEngine,
     FleetServer,
@@ -277,3 +283,144 @@ def test_engine_cancel_resets_decode_state():
     eng2.run_until_drained()
     ref = _greedy_reference(model, params, [3, 14, 15], 4, 32)
     assert req.out_tokens == ref
+
+
+# ----------------------------------------------------------- serving spans
+SPAN_FLEETS = {"mixed": "a=2x4,b=1x2", "disagg": "p=2.0^prefill,d=1.0x4^decode"}
+
+
+def _span_requests():
+    return [Request(rid=i, prompt=[1 + (3 * i) % 11, 7, 2, 5][: 2 + i % 3],
+                    max_new_tokens=2 + i % 4)
+            for i in range(10)]
+
+
+def _cluster_serve(kind, model, params, tracer):
+    reqs = _span_requests()
+    Cluster(SPAN_FLEETS[kind], backend="wallclock", trace=tracer).serve(
+        ServeJob(reqs, model=model, params=params, max_seq=32,
+                 max_queue_depth=2))
+    return reqs
+
+
+@pytest.fixture(scope="module")
+def span_runs():
+    """Each fleet kind served once traced and once untraced, through
+    Cluster.serve on the wall-clock backend (so waves, ticks and engine
+    phases all run)."""
+    model, params = tiny_model()
+    out = {}
+    for kind in SPAN_FLEETS:
+        tracer = Tracer()
+        traced = _cluster_serve(kind, model, params, tracer)
+        out[kind] = (tracer, traced, _cluster_serve(kind, model, params, None))
+    return out
+
+
+@pytest.mark.parametrize("kind", SPAN_FLEETS)
+def test_spans_nest_inside_their_parents(span_runs, kind):
+    tracer = span_runs[kind][0]
+    by_id = {s.id: s for s in tracer.spans}
+    assert all(s.t1_ns is not None and s.t1_ns >= s.t0_ns for s in tracer.spans)
+    children = {}
+    for s in tracer.spans:
+        if s.parent is not None:
+            p = by_id[s.parent]
+            assert p.t0_ns <= s.t0_ns and s.t1_ns <= p.t1_ns, (p.name, s.name)
+            assert s.worker == p.worker or p.worker is None
+            children.setdefault(p.id, []).append(s)
+    for kids in children.values():
+        kids.sort(key=lambda s: s.t0_ns)
+        for a, b in zip(kids, kids[1:]):
+            assert a.t1_ns <= b.t0_ns, (a.name, b.name)
+    parent_name = {s.name: by_id[s.parent].name if s.parent else None
+                   for s in tracer.spans}
+    assert parent_name["engine.step"] == "runtime.tick"
+    assert parent_name["engine.step.fetch"] == "engine.step"
+    assert parent_name["runtime.tick"] == ("serve.wave" if kind == "mixed" else None)
+    if kind == "disagg":
+        assert parent_name["engine.prefill"] == "runtime.tick"
+        assert parent_name["engine.prefill.device"] == "engine.prefill"
+    # Every step that ran the device has its four phases.
+    for s in tracer.spans:
+        if s.name == "engine.step" and s.attrs["active"]:
+            names = [k.name for k in children[s.id]]
+            assert names == ["engine.step.prep", "engine.step.device",
+                             "engine.step.fetch", "engine.step.sample"]
+
+
+@pytest.mark.parametrize("kind", SPAN_FLEETS)
+def test_every_request_has_its_wait_spans(span_runs, kind):
+    tracer, reqs, _ = span_runs[kind]
+    names = {}
+    for s in tracer.spans:
+        if s.keyed:
+            names.setdefault(s.rid, []).append(s.name)
+    want = (["request.backlog", "request.queue"] if kind == "mixed"
+            else ["request.queue", "request.handoff"])
+    assert {r.rid: names[r.rid] for r in reqs} == {r.rid: want for r in reqs}
+    for s in tracer.spans:
+        if s.name in ("request.queue", "request.handoff"):
+            assert s.worker in ("a", "b", "p", "d")
+    if kind == "mixed":
+        # Two requests a replica a wave: the fleet needed several waves.
+        assert sum(s.name == "serve.wave" for s in tracer.spans) == 3
+
+
+@pytest.mark.parametrize("kind", SPAN_FLEETS)
+def test_step_counters_add_up_to_the_decoded_tokens(span_runs, kind):
+    tracer, reqs, _ = span_runs[kind]
+    steps = [s for s in tracer.spans if s.name == "engine.step"]
+    decoded = sum(len(r.out_tokens) for r in reqs)
+    if kind == "disagg":
+        decoded -= len(reqs)            # each first token came from prefill
+    assert sum(s.attrs["sampled"] for s in steps) == decoded
+    for s in steps:
+        a = s.attrs
+        assert a["active"] == a["feeding"] + a["sampled"] <= a["max_batch"]
+    fetch = [s for s in tracer.spans if s.name == "engine.step.fetch"]
+    vocab = tiny_model()[0].cfg.vocab_size
+    assert fetch and all(s.attrs["bytes"] >= 4 * vocab for s in fetch)
+
+
+@pytest.mark.parametrize("kind", SPAN_FLEETS)
+def test_traced_serve_tokens_bitwise_identical(span_runs, kind):
+    _, traced, untraced = span_runs[kind]
+    assert [r.out_tokens for r in traced] == [r.out_tokens for r in untraced]
+
+
+@pytest.mark.parametrize("kind", SPAN_FLEETS)
+def test_untraced_serve_builds_no_span(monkeypatch, kind):
+    def refuse(*a, **k):
+        raise AssertionError("a span was entered with no tracer")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(obs_trace.Span, "__init__", refuse)
+    model, params = tiny_model()
+    reqs = _cluster_serve(kind, model, params, None)
+    assert all(len(r.out_tokens) == r.max_new_tokens for r in reqs)
+
+
+def test_profiler_annotations_match_step_spans(tmp_path):
+    """The spans' annotations land on the profiler's host plane: one
+    ``repro.engine.step`` per in-memory ``engine.step`` span, each as long
+    within 50 microseconds."""
+    model, params = tiny_model()
+    tracer = Tracer()
+    _cluster_serve("mixed", model, params, Tracer())      # compile first
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _cluster_serve("mixed", model, params, tracer)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    annotated = sorted(
+        (e.start_ns, e.duration_ns)
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name == "repro.engine.step")
+    spans = [s for s in tracer.spans if s.name == "engine.step"]
+    assert len(annotated) == len(spans) > 0
+    for (_, dur), s in zip(annotated, spans):
+        assert abs(dur - (s.t1_ns - s.t0_ns)) <= 50_000
