@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ..kernels.flash_attention.ops import mha as flash_mha
 from ..kernels.prefill.ops import prefill_attention
 from .config import ModelConfig
-from .layers import apply_rope, dense_init, dtype_of, rms_norm
+from .layers import apply_rope, cache_write, dense_init, dtype_of, pos_column, rms_norm
 
 NEG_INF = -1e30
 
@@ -213,33 +213,15 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int) -> KVCache:
     return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
 
 
-def _pos2d(pos: jax.Array, b: int) -> jax.Array:
-    """Normalize pos (scalar or (B,)) to an int (B, 1) matrix."""
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        return jnp.broadcast_to(jnp.reshape(pos, (1, 1)), (b, 1))
-    return pos[:, None]
-
-
-def _cache_write(cache_arr: jax.Array, new: jax.Array, pos: jax.Array, mode: str):
-    """Write (B,1,H,D) `new` at sequence index `pos` (scalar or per-batch
-    (B,)) of a (B,S,H,D) cache.  Vector pos always uses the one-hot path."""
-    pos = jnp.asarray(pos)
-    if mode == "dus" and pos.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache_arr, new.astype(cache_arr.dtype), pos, axis=1
-        )
-    oh = (jnp.arange(cache_arr.shape[1])[None, :] == _pos2d(pos, cache_arr.shape[0]))
-    return jnp.where(oh[..., None, None], new.astype(cache_arr.dtype), cache_arr)
-
-
 def attention_decode(
     p: dict, cfg: ModelConfig, x: jax.Array, cache: KVCache, pos: jax.Array,
-    *, cross: bool = False, cross_len: jax.Array | None = None,
+    *, cross: bool = False, cross_len: jax.Array | None = None, layer=None,
 ) -> tuple[jax.Array, KVCache]:
-    """One-token decode.  x: (B,1,d).  pos: scalar current index.
+    """One-token decode.  x: (B,1,d).  pos: scalar or per-slot (B,) index.
 
-    Self-attn: writes K/V at `pos`, attends over cache[<= pos].
+    Self-attn: writes K/V at `pos`, attends over cache[<= pos].  Given
+    `layer`, `cache` is the whole stack (L,B,S,Hkv,Dh): the row goes into
+    layer `layer` of it, that layer is read, and the stack is returned.
     Cross-attn (enc-dec): cache holds the encoder memory; no write.
     """
     b = x.shape[0]
@@ -254,11 +236,15 @@ def attention_decode(
             cross_len if cross_len is not None else k.shape[1]
         )
     else:
-        pos_b = _pos2d(pos, b)
+        pos_b = pos_column(pos, b)
         q, k_t, v_t = _project_qkv(p, cfg, x, x, pos_b, pos_b)
-        k = _cache_write(cache.k, k_t, pos, cfg.cache_update)
-        v = _cache_write(cache.v, v_t, pos, cfg.cache_update)
-        cache = KVCache(k=k, v=v)
+        cache = KVCache(
+            k=cache_write(cache.k, k_t, pos, cfg.cache_update, layer),
+            v=cache_write(cache.v, v_t, pos, cfg.cache_update, layer),
+        )
+        k, v = cache.k, cache.v
+        if layer is not None:
+            k, v = k[layer], v[layer]
         valid = jnp.arange(k.shape[1])[None, :] <= pos_b
     kv_mask = jnp.broadcast_to(valid, (b, k.shape[1]))
     out = _sdpa_full(

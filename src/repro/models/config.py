@@ -96,6 +96,8 @@ class ModelConfig:
     rope_theta: float = 1e6
     attn_chunk: int = 1024        # jnp flash chunking threshold / q-block
     attn_chunk_k: int = 0         # kv-block size (0 = same as attn_chunk)
+    # scalar-pos decode write: "dus" writes the row in place, "onehot"
+    # selects over the sequence axis; a per-slot pos always writes rows
     cache_update: Literal["dus", "onehot"] = "dus"
 
     # embeddings / head

@@ -149,3 +149,41 @@ def lm_logits(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         ).astype(logits.dtype)
         logits = logits + mask
     return logits
+
+
+# ----------------------------------------------------------------- decode cache
+def pos_column(pos, b: int) -> jax.Array:
+    """A decode position, scalar or per-slot ``(B,)``, as an int ``(B, 1)``."""
+    pos = jnp.asarray(pos)
+    if pos.ndim == 0:
+        return jnp.broadcast_to(jnp.reshape(pos, (1, 1)), (b, 1))
+    return pos[:, None]
+
+
+def cache_write(cache: jax.Array, new: jax.Array, pos, mode: str,
+                layer=None) -> jax.Array:
+    """Write ``new``, ``(B, 1, ...)``, at sequence index ``pos`` (scalar or
+    per-slot ``(B,)``) of a cache: one layer's ``(B, S, ...)`` or, given
+    ``layer``, a stack's ``(L, B, S, ...)`` at that layer.  Returns the whole
+    cache.
+
+    The write scatters one row per slot, in place where the cache is donated
+    or carried; a pos outside ``[0, S)`` writes nothing.  A scatter, not a
+    dynamic_update_slice, also for a scalar pos: where the sequence axis is
+    sharded, the partitioner turns a dynamic_update_slice into a select over
+    the whole local shard, which for a stack is every layer's cache.  With
+    ``mode == "onehot"`` a scalar pos selects over the layer's sequence
+    axis instead."""
+    new = new.astype(cache.dtype)
+    pos = jnp.asarray(pos)
+    if mode == "onehot" and pos.ndim == 0:
+        one = cache if layer is None else cache[layer]
+        hit = jnp.arange(one.shape[1]) == pos
+        one = jnp.where(hit.reshape((1, -1) + (1,) * (one.ndim - 2)), new, one)
+        if layer is None:
+            return one
+        return jax.lax.dynamic_update_index_in_dim(cache, one, layer, 0)
+    rows = jnp.arange(new.shape[0]) if pos.ndim else slice(None)
+    idx = (rows, pos) if layer is None else (layer, rows, pos)
+    return cache.at[idx].set(new[:, 0], unique_indices=True, mode="drop",
+                             wrap_negative_indices=False)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import apply_rope, dense_init, dtype_of, rms_norm
+from .layers import apply_rope, cache_write, dense_init, dtype_of, pos_column, rms_norm
 
 NEG_INF = -1e30
 
@@ -126,43 +126,32 @@ def init_mla_cache(cfg: ModelConfig, batch: int, seq: int) -> MLACache:
     )
 
 
-def _pos2d(pos, b: int):
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        return jnp.broadcast_to(jnp.reshape(pos, (1, 1)), (b, 1))
-    return pos[:, None]
-
-
-def _cache_write(arr: jax.Array, new: jax.Array, pos, mode: str):
-    pos = jnp.asarray(pos)
-    if mode == "dus" and pos.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(arr, new.astype(arr.dtype), pos, axis=1)
-    oh = jnp.arange(arr.shape[1])[None, :] == _pos2d(pos, arr.shape[0])
-    return jnp.where(oh[..., None], new.astype(arr.dtype), arr)
-
-
 def mla_decode(
-    p: dict, cfg: ModelConfig, x: jax.Array, cache: MLACache, pos
+    p: dict, cfg: ModelConfig, x: jax.Array, cache: MLACache, pos, layer=None
 ) -> tuple[jax.Array, MLACache]:
-    """Absorbed-form decode: attention entirely in the 512-d latent space."""
+    """Absorbed-form decode: attention entirely in the 512-d latent space.
+    Given `layer`, `cache` is the whole stack, as in `attention_decode`."""
     m = cfg.mla
     b = x.shape[0]
-    pos_b = _pos2d(pos, b)
+    pos_b = pos_column(pos, b)
     q_nope, q_rope, c_kv_t, k_rope_t = _latents(p, cfg, x, pos_b)
     cache = MLACache(
-        c_kv=_cache_write(cache.c_kv, c_kv_t, pos, cfg.cache_update),
-        k_rope=_cache_write(cache.k_rope, k_rope_t, pos, cfg.cache_update),
+        c_kv=cache_write(cache.c_kv, c_kv_t, pos, cfg.cache_update, layer),
+        k_rope=cache_write(cache.k_rope, k_rope_t, pos, cfg.cache_update, layer),
     )
+    c_kv, k_rope = cache.c_kv, cache.k_rope
+    if layer is not None:
+        c_kv, k_rope = c_kv[layer], k_rope[layer]
     # Absorb W_UK into the query: q_lat (B,1,H,kv_lora).
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wuk"])
     scale = 1.0 / (m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5
-    ckv = cache.c_kv.astype(x.dtype)
-    krp = cache.k_rope.astype(x.dtype)
+    ckv = c_kv.astype(x.dtype)
+    krp = k_rope.astype(x.dtype)
     logits = (
         jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv)
         + jnp.einsum("bqhk,bsk->bhqs", q_rope, krp)
     ).astype(jnp.float32) * scale
-    valid = jnp.arange(cache.c_kv.shape[1])[None, :] <= pos_b   # (B, S)
+    valid = jnp.arange(c_kv.shape[1])[None, :] <= pos_b   # (B, S)
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     attn = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     ctx_lat = jnp.einsum("bhqs,bsr->bqhr", attn, ckv)          # (B,1,H,kv_lora)

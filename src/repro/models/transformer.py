@@ -7,7 +7,9 @@ layers; deepseek 60).  Stacked period params/caches carry a leading
 ``n_periods`` axis on every leaf.
 
 Modes: "train" (no cache), "prefill" (returns caches), "decode" (consumes and
-returns caches, one token).
+returns caches, one token).  Decode carries the stacked period caches through
+the scan and writes each layer's new row into them in place, so a step moves
+one row per slot and layer, not the whole cache.
 """
 
 from __future__ import annotations
@@ -84,13 +86,33 @@ def cross_kv(p_cross: dict, cfg: ModelConfig, memory: jax.Array) -> KVCache:
 
 
 # -------------------------------------------------------------------- layer apply
+def _layer_of(tree, layer):
+    """Entry ``layer`` of every stacked leaf (the tree itself when None)."""
+    if layer is None:
+        return tree
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), tree)
+
+
+def _put_layer(stack, one, layer):
+    """``stack`` with entry ``layer`` replaced by ``one`` (``one`` when None)."""
+    if layer is None:
+        return one
+    return jax.tree.map(
+        lambda s, o: jax.lax.dynamic_update_index_in_dim(s, o, layer, 0),
+        stack, one)
+
+
 def apply_layer(
     p: dict, cfg: ModelConfig, spec: LayerSpec, x: jax.Array, *,
     mode: str, positions=None, cache: dict | None = None, pos=None,
     causal: bool = True, cross_memory: jax.Array | None = None,
-    mem_positions=None, capacities=None,
+    mem_positions=None, capacities=None, layer=None,
 ):
-    """Returns (x, new_cache | None, aux_loss scalar)."""
+    """Returns (x, new_cache | None, aux_loss scalar).
+
+    Decode given ``layer``: ``cache`` holds the whole period stack, this
+    layer is entry ``layer`` of it, and the stack is returned updated."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict[str, Any] = {}
     h = apply_norm(cfg, p["norm1"], x)
@@ -101,7 +123,8 @@ def apply_layer(
             a, c = attention_prefill(p["attn"], cfg, h, positions)
             new_cache["self"] = c
         else:
-            a, c = attention_decode(p["attn"], cfg, h, cache["self"], pos)
+            a, c = attention_decode(p["attn"], cfg, h, cache["self"], pos,
+                                    layer=layer)
             new_cache["self"] = c
     elif spec.mixer == "mla":
         if mode == "train":
@@ -110,7 +133,7 @@ def apply_layer(
             a, c = mla_prefill(p["mla"], cfg, h, positions)
             new_cache["self"] = c
         else:
-            a, c = mla_decode(p["mla"], cfg, h, cache["self"], pos)
+            a, c = mla_decode(p["mla"], cfg, h, cache["self"], pos, layer=layer)
             new_cache["self"] = c
     elif spec.mixer == "mamba":
         if mode in ("train", "prefill"):
@@ -118,8 +141,9 @@ def apply_layer(
             if mode == "prefill":
                 new_cache["self"] = c
         else:
-            a, c = mamba_decode(p["mamba"], cfg, h, cache["self"])
-            new_cache["self"] = c
+            a, c = mamba_decode(p["mamba"], cfg, h,
+                                _layer_of(cache["self"], layer))
+            new_cache["self"] = _put_layer(cache["self"], c, layer)
     else:
         raise ValueError(spec.mixer)
     x = x + a
@@ -139,7 +163,8 @@ def apply_layer(
             )
         else:
             a, _ = attention_decode(
-                p["cross"], cfg, h, cache["cross"], None, cross=True
+                p["cross"], cfg, h, _layer_of(cache["cross"], layer), None,
+                cross=True,
             )
             new_cache["cross"] = cache["cross"]
         x = x + a
@@ -244,23 +269,26 @@ def apply_stack(
         new_prefix.append(nc)
 
     def body(carry, xs):
-        h, aux_acc = carry
+        # Decode carries the period caches and reads the layer index from
+        # xs; the other modes return each layer's new cache as a scan output.
+        h, aux_acc, stack = carry
         h = _sp_constrain(cfg, h)
-        per_params = xs[0] if mode == "decode" else xs
-        per_cache = xs[1] if mode == "decode" else None
+        per_params, layer = xs if mode == "decode" else (xs, None)
         ncs = {}
         for i, spec in enumerate(pattern):
-            c = per_cache[f"pos{i}"] if per_cache is not None else None
+            c = stack[f"pos{i}"] if stack is not None else None
             h, nc, aux = apply_layer(
                 per_params[f"pos{i}"], cfg, spec, h, mode=mode,
                 positions=positions, cache=c, pos=pos, causal=causal,
                 cross_memory=cross_memory, mem_positions=mem_positions,
-                capacities=capacities,
+                capacities=capacities, layer=layer,
             )
             aux_acc = aux_acc + aux
             if nc is not None:
                 ncs[f"pos{i}"] = nc
-        return (h, aux_acc), (ncs if ncs else None)
+        if mode == "decode":
+            return (h, aux_acc, ncs), None
+        return (h, aux_acc, None), (ncs if ncs else None)
 
     if remat and mode == "train":
         policy = (
@@ -270,14 +298,15 @@ def apply_stack(
         )
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
 
-    xs = (
-        (params["periods"], caches["periods"])
-        if mode == "decode"
-        else params["periods"]
+    xs, stack = params["periods"], None
+    if mode == "decode":
+        n_periods = jax.tree.leaves(xs)[0].shape[0]
+        xs, stack = (xs, jnp.arange(n_periods)), caches["periods"]
+    (x, aux_total, stack), period_caches = jax.lax.scan(
+        body, (x, aux_total, stack), xs, unroll=True if cfg.full_unroll else 1
     )
-    (x, aux_total), period_caches = jax.lax.scan(
-        body, (x, aux_total), xs, unroll=True if cfg.full_unroll else 1
-    )
+    if mode == "decode":
+        period_caches = stack
     if mode == "train":
         return x, None, aux_total
     out_caches: dict[str, Any] = {"periods": period_caches}
