@@ -1020,6 +1020,7 @@ class Cluster:
                 srep.ttft_split.as_dict() if srep.ttft_split else None
             )
             metrics["n_handoffs"] = srep.n_handoffs
+            metrics["handoff_bytes_peak"] = srep.handoff_bytes_peak
         if self._auto_profiles:
             metrics["auto_profiles"] = dict(self._auto_profiles)
         return RunReport(

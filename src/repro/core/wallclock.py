@@ -197,9 +197,9 @@ class WallclockBackend(ExecutionBackend):
 
     def step_clock(self, worker: Any) -> float:
         """Measured wall seconds per engine step for ``worker`` (EMA over
-        ``timed_tick``), seeded at the calibrated unit time until the first
-        real tick lands — never the modeled ``1/perf`` clock, which is on a
-        different (simulated-seconds) scale entirely.  Wired into
+        this job's ``timed_tick``), seeded at the calibrated unit time until
+        the job's first real tick lands — never the modeled ``1/perf`` clock,
+        which is on a different (simulated-seconds) scale entirely.  Wired into
         ``EngineExecutor.step_clock`` so serve heartbeats report measured
         tokens/sec."""
         return self._tick_ema.get(getattr(worker, "name", ""), self._unit_s)
@@ -223,6 +223,10 @@ class WallclockBackend(ExecutionBackend):
             self._cost_ref = max(float(executor.cost(0)), _EPS)
         self._job_t0 = time.perf_counter()
         self._n_launched = 0
+        # Each job learns its own step clock.  Carried over, the last job's
+        # ticks (a warm-up's compiles among them) would set this job's first
+        # ticks, and with them the order of its workers' steps.
+        self._tick_ema.clear()
 
     def end_job(self, res: RuntimeResult) -> None:
         wall = (time.perf_counter() - self._job_t0) if self._job_t0 else 0.0

@@ -22,6 +22,9 @@ produced ``KVHandoff`` is retained by the executor: a decode replica dying
 mid-stream cancels the slot (``DecodeEngine.cancel``) and the heir
 ``insert``s the *same* handoff — the first token is never recomputed, the
 continuation is bitwise-identical, and the request completes exactly once.
+A handoff is released when its request completes, since nothing re-inserts a
+finished request; ``handoff_bytes_held`` and ``handoff_bytes_peak`` count
+the cache bytes retained now and at most.
 
 Every request carries TTFT-split timestamps: queue (arrival -> prefill
 begin), prefill (begin -> handoff ready), handoff (ready -> decode insert,
@@ -179,10 +182,13 @@ class DisaggExecutor(GrainExecutor):
         )
         for name, eng in self.engines.items():
             self._validate_engine(name, eng)
-        # KV handoffs, retained past insertion: the exactly-once anchor — a
-        # killed decode replica's heir re-inserts the same handoff.
+        # KV handoffs, retained past insertion until their request
+        # completes: the exactly-once anchor — a killed decode replica's heir
+        # re-inserts the same handoff.
         self.handoffs: dict[int, KVHandoff] = {}
         self.n_handoffs = 0
+        self.handoff_bytes_held = 0
+        self.handoff_bytes_peak = 0
         # Observability (all keyed by request index, runtime-clock seconds).
         self.first_token_s: dict[int, float] = {}
         self.prefill_begin_s: dict[int, float] = {}
@@ -319,6 +325,9 @@ class DisaggExecutor(GrainExecutor):
                 h = self.engine_for(worker).prefill(r)
                 self.handoffs[g] = h
                 self.n_handoffs += 1
+                self.handoff_bytes_held += h.nbytes
+                self.handoff_bytes_peak = max(self.handoff_bytes_peak,
+                                              self.handoff_bytes_held)
                 self.ready_s[g] = now_s
                 self.first_token_s[g] = now_s
                 if self.tracer is not None:
@@ -326,12 +335,16 @@ class DisaggExecutor(GrainExecutor):
                                      grain=g)
                     # Ends where a decode engine inserts it.
                     self.tracer.open("request.handoff", r.rid)
-                done.append((g, h))
+                # The grain's value is the request: the handoff lives in
+                # self.handoffs alone, so releasing it there frees its cache.
+                done.append((g, r))
             return done
         finished = self.engine_for(worker).step()
         out = [(self.n + self._grain_of[r.rid], r) for r in finished]
         for g in self._instant.pop(name, []):
             out.append((g, self.requests[g - self.n]))
+        for g, _ in out:
+            self.handoff_bytes_held -= self.handoffs.pop(g - self.n).nbytes
         if self.on_finish is not None:
             for g, r in out:
                 i = g - self.n

@@ -25,13 +25,15 @@ for), ``.fetch`` (the logits pulled to the host) and ``.sample`` (the
 per-slot argmax and bookkeeping), and counters ``active``, ``feeding``
 (active slots that consumed a prompt token and sampled nothing),
 ``sampled`` and ``max_batch``; ``engine.prefill`` with ``.device`` and
-``.fetch``; ``engine.insert``.  Admission closes a request's
-``request.queue`` span and ``insert`` its ``request.handoff``.
+``.fetch``, and ``handoff_bytes`` (its ``KVHandoff``'s cache);
+``engine.insert``.  Admission closes a request's ``request.queue`` span and
+``insert`` its ``request.handoff``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +80,33 @@ class KVHandoff:
     caches: object           # batch-1 cache pytree, seq dim = bucket
     source: str              # producing engine (provenance / debugging)
     bucket: int
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of ``caches`` (the memory this handoff holds while it is
+        kept for a re-insert)."""
+        return sum(x.nbytes for x in jax.tree_util.tree_leaves(self.caches))
+
+
+# The engine's cache is donated, so an insert writes its lane in place
+# instead of holding a second whole cache while it copies.
+@functools.partial(jax.jit, donate_argnums=0)
+def _insert_lane(caches, part, idx):
+    """``caches`` with ``part`` (a batch-1 handoff cache) written into slot
+    lane ``idx``: the batch axis is the first where the handoff slice is 1 and
+    the engine cache is wider; the (shorter) bucket seq axis starts at 0.
+    Garbage beyond the handoff's ``pos`` is never attended."""
+
+    def put(full, p):
+        starts = [0] * full.ndim
+        for a in range(full.ndim):
+            if p.shape[a] != full.shape[a] and p.shape[a] == 1:
+                starts[a] = idx
+                break
+        return jax.lax.dynamic_update_slice(full, p.astype(full.dtype),
+                                            tuple(starts))
+
+    return jax.tree_util.tree_map(put, caches, part)
 
 
 class DecodeEngine:
@@ -175,7 +204,7 @@ class DecodeEngine:
         tracer = self.tracer
         span = NO_SPAN if tracer is None else tracer.span
         with span("engine.prefill", worker=self.name, rid=req.rid, length=L,
-                  bucket=bucket):
+                  bucket=bucket) as pf:
             toks = np.zeros((1, bucket), np.int64)
             toks[0, :L] = req.prompt
             fn = self._prefills.get(bucket)
@@ -201,10 +230,13 @@ class DecodeEngine:
                     int(lg.argmax()) if self.greedy
                     else int(self.rng.choice(self.model.cfg.vocab_size))
                 )
+            handoff = KVHandoff(req=req, pos=L, first_token=first,
+                                caches=caches, source=self.name, bucket=bucket)
+            if tracer is not None:
+                pf.set(handoff_bytes=handoff.nbytes)
         self.prompt_fed += L
         self.tokens_out += 1
-        return KVHandoff(req=req, pos=L, first_token=first, caches=caches,
-                         source=self.name, bucket=bucket)
+        return handoff
 
     def insert(self, handoff: KVHandoff) -> int:
         """Continue a prefilled request on this engine.  Returns the slot
@@ -247,20 +279,8 @@ class DecodeEngine:
                 f"engine {self.name!r}: no free slot for handoff insert"
             )
 
-        def put(full, part):
-            # The batch axis is the first axis where the handoff slice is 1
-            # and the engine cache is wider; the (shorter) bucket seq axis
-            # starts at 0.  Garbage beyond `pos` is never attended.
-            starts = [0] * full.ndim
-            for a in range(full.ndim):
-                if part.shape[a] != full.shape[a] and part.shape[a] == 1:
-                    starts[a] = idx
-                    break
-            return jax.lax.dynamic_update_slice(
-                full, part.astype(full.dtype), tuple(starts)
-            )
-
-        self.caches = jax.tree_util.tree_map(put, self.caches, handoff.caches)
+        self.caches = _insert_lane(self.caches, handoff.caches,
+                                   jnp.int32(idx))
         slot = self.slots[idx]
         slot.req = r
         slot.pos = handoff.pos

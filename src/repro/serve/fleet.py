@@ -16,10 +16,11 @@ to its start; events landing past a wave's end carry over to the next wave
 (the runtime's pending-event semantics).
 
 With a tracer on the dispatcher's runtime, each wave is a ``serve.wave``
-span, and each request's wait before it reaches an engine is two request
-spans: ``request.backlog`` while it waits for its wave, ``request.queue``
-from its wave's dispatch (or, in an open-loop stream, the stream's start)
-until an engine admits it to a slot or its prefill begins.
+span (an open-loop stream is one wave; a disaggregated one's span carries
+``handoff_bytes_peak``), and each request's wait before it reaches an engine
+is two request spans: ``request.backlog`` while it waits for its wave,
+``request.queue`` from its wave's dispatch (or, in an open-loop stream, the
+stream's start) until an engine admits it to a slot or its prefill begins.
 """
 
 from __future__ import annotations
@@ -190,6 +191,7 @@ class StreamReport:
     ttft_split: TTFTSplit | None = None
     role_stats: tuple[RoleStats, ...] = ()
     n_handoffs: int = 0
+    handoff_bytes_peak: int = 0    # most KV handoff cache bytes retained at once
 
     @property
     def shed_rate(self) -> float:
@@ -484,19 +486,24 @@ class FleetServer:
                     joined.append(rep.name)
 
         try:
-            res, run, executor = self.dispatcher.dispatch_stream(
-                {n: self.engines[n] for n in live if n in self.engines},
-                requests,
-                arrive,
-                timeline=timeline,
-                max_queue_depth=self.max_queue_depth,
-                overflow=overflow,
-                engine_factory=(
-                    self._factory if self.engine_factory is not None else None
-                ),
-                on_finish=on_finish,
-                roles=roles,
-            )
+            with stream_tracer.span("serve.wave", wave=0,
+                                    n_requests=len(requests)) as wave:
+                res, run, executor = self.dispatcher.dispatch_stream(
+                    {n: self.engines[n] for n in live if n in self.engines},
+                    requests,
+                    arrive,
+                    timeline=timeline,
+                    max_queue_depth=self.max_queue_depth,
+                    overflow=overflow,
+                    engine_factory=(
+                        self._factory if self.engine_factory is not None
+                        else None
+                    ),
+                    on_finish=on_finish,
+                    roles=roles,
+                )
+                if roles:
+                    wave.set(handoff_bytes_peak=executor.handoff_bytes_peak)
         finally:
             if ephemeral:
                 rt.tracer = None
@@ -540,7 +547,7 @@ class FleetServer:
 
         ttft_split: TTFTSplit | None = None
         role_stats: tuple[RoleStats, ...] = ()
-        n_handoffs = 0
+        n_handoffs = peak = 0
         if roles:
             rel_arrive = [start + a for a in arrive]
             finish = {g: done[off + g][0] for g in range(len(requests))
@@ -562,6 +569,7 @@ class FleetServer:
                 )
             )
             n_handoffs = executor.n_handoffs
+            peak = executor.handoff_bytes_peak
 
         return StreamReport(
             n_requests=len(requests),
@@ -584,6 +592,7 @@ class FleetServer:
             ttft_split=ttft_split,
             role_stats=role_stats,
             n_handoffs=n_handoffs,
+            handoff_bytes_peak=peak,
         )
 
     # -- fleet management (between waves) ------------------------------------

@@ -9,6 +9,8 @@ run in milliseconds in tier-1.  Shared by ``test_fleet.py`` and
 
 import dataclasses
 
+import numpy as np
+
 from repro.serve import KVHandoff, Request
 
 
@@ -128,7 +130,7 @@ class StubEngine:
         self.tokens_out += 1
         return KVHandoff(
             req=req, pos=L, first_token=stub_token(req.rid, 0),
-            caches={"stub": req.rid}, source=self.name,
+            caches={"stub": np.asarray(req.rid)}, source=self.name,
             bucket=_stub_bucket(L, self.max_seq),
         )
 

@@ -1,10 +1,10 @@
 """Compiles for a described TPU v5e chip, at real widths, with none attached.
 
 The TPU compiler is installed beside the CPU backend, so the kernels of the
-main path and one whole Qwen2-1.5B decode step are compiled here for one chip
-of a described ``v5e:2x2`` topology.  This catches what interpret mode
-cannot: block shapes off the (8, 128) tiling, primitives Mosaic cannot lower,
-and programs that do not fit the chip.  Nothing runs, so nothing here is a
+main path, the decode step of each chip cell's model and the Qwen3-8B prefill
+are compiled here for one chip of a described ``v5e:2x2`` topology.  This
+catches what interpret mode cannot: block shapes off the (8, 128) tiling,
+primitives Mosaic cannot lower, and programs that do not fit the chip.  Nothing runs, so nothing here is a
 time or a result.
 
 The topology is described inside a fixture, never at import: only one process
@@ -24,6 +24,8 @@ from repro.kernels.mamba_scan.ops import ssd
 from repro.kernels.matmul.ops import matmul
 from repro.kernels.prefill.ops import prefill_attention
 from repro.models.model import Model
+
+import repro.models.attention as attention
 
 BF16 = jnp.bfloat16
 
@@ -96,45 +98,51 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen2_1_5b_decode_step_compiles_for_v5e(one_chip):
-    model = Model(get_config("qwen2-1.5b"))
+# The decode step at chat engine ``a``'s shape in each chip cell: Qwen2-1.5B
+# whole at 32 slots, and Qwen3-8B (32/8 heads of 128, d 4096, QK-norm) cut
+# to an 18-layer stage at 16 slots; each row is (config, slots, max_seq).
+DECODE = {
+    "qwen2-1.5b": (lambda: get_config("qwen2-1.5b"), 32, 2048),
+    "qwen3-8b-18l": (lambda: get_config("qwen3-8b", n_layers=18), 16, 2048),
+}
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
 
-    params = on_chip(model.abstract_params())
-    caches = on_chip(jax.eval_shape(lambda: model.init_cache(8, 2048)))
-    toks = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+def _on_chip(tree, one_chip):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+
+
+def _decode_step(one_chip, name):
+    config, slots, seq = DECODE[name]
+    model = Model(config())
+    params = _on_chip(model.abstract_params(), one_chip)
+    caches = _on_chip(jax.eval_shape(lambda: model.init_cache(slots, seq)),
+                      one_chip)
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
         params, caches, toks, pos).compile()
+    return compiled, caches
+
+
+@pytest.mark.parametrize("name", DECODE)
+def test_decode_step_compiles_for_v5e(one_chip, name):
+    compiled, _ = _decode_step(one_chip, name)
     mem = compiled.memory_analysis()
     # Weights and cache stay within one chip's 16 GB.
     assert mem.argument_size_in_bytes < 16 * 2**30
 
 
-def test_qwen2_1_5b_decode_step_writes_cache_in_place(one_chip):
-    """At chat engine ``a``'s shape (32 slots x 2048) the step writes its row
-    per slot and layer into the donated cache: no second cache, the output
-    aliases the input, and no copy of the stacked cache.  Before the rows were
-    scattered into a carried stack, the scan re-wrote each layer's whole cache
-    into a new stack and copied that into the output: 1 946 479 616 bytes of
+@pytest.mark.parametrize("name", DECODE)
+def test_decode_step_writes_cache_in_place(one_chip, name):
+    """The step writes its row per slot and layer into the donated cache: no
+    second cache, the output aliases the input, and no copy of the stacked
+    cache.  Before the rows were scattered into a carried stack, the scan
+    re-wrote each layer's whole cache into a new stack and copied that into
+    the output: for Qwen2-1.5B at 32 x 2048, 1 946 479 616 bytes of
     temporaries for a 1 879 048 192-byte cache."""
-    model = Model(get_config("qwen2-1.5b"))
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
-
-    params = on_chip(model.abstract_params())
-    caches = on_chip(jax.eval_shape(lambda: model.init_cache(32, 2048)))
-    toks = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
-        params, caches, toks, pos).compile()
+    compiled, caches = _decode_step(one_chip, name)
     cache_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(caches))
     mem = compiled.memory_analysis()
@@ -145,3 +153,32 @@ def test_qwen2_1_5b_decode_step_writes_cache_in_place(one_chip):
     copies = [line for line in compiled.as_text().splitlines()
               if f"= {stacked}" in line and " copy(" in line]
     assert not copies, copies
+
+
+def test_qwen3_8b_prefill_runs_the_pallas_kernel(one_chip, monkeypatch):
+    """Qwen3-8B's prefill at the 4096 bucket (one 18-layer stage, GQA-4:
+    32 query heads over 8 KV heads, unpadded) compiles for the chip with the
+    Pallas prefill kernel in it."""
+
+    class AsOnTPU:
+        """The attention module's view of JAX: the backend is a TPU, so it
+        takes its TPU path (the compiled Pallas prefill)."""
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def default_backend():
+            return "tpu"
+
+    monkeypatch.setattr(attention, "jax", AsOnTPU())
+    model = Model(get_config("qwen3-8b", n_layers=18))
+    params = _on_chip(model.abstract_params(), one_chip)
+    toks = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    last = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def run(params, toks, last_pos):
+        return model.prefill(params, {"tokens": toks}, last_pos=last_pos)
+
+    compiled = jax.jit(run).lower(params, toks, last).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -80,7 +80,7 @@ def test_length_bucket_ladder():
         length_bucket(129, 128)
 
 
-@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2)])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2), (8, 2)])
 def test_prefill_kernel_matches_ref(hq, hkv):
     b, s, d = 1, 32, 16
     q = jnp.asarray(RNG.standard_normal((b, s, hq, d)), jnp.float32)
@@ -270,6 +270,63 @@ def test_stream_disagg_double_kill_exactly_once():
     assert total_inserts >= 8
     for name, eng in engines.items():
         assert eng.active == 0, (name, eng.active)
+
+
+def _tiny_disagg_stream(timeline=()):
+    """Eight requests (one finishing at insert, max_new 1) served as one
+    disaggregated stream of real tiny engines through the dispatcher, which
+    hands back the executor.  Returns (executor, requests, bytes a handoff
+    holds per cache position)."""
+    model, params = tiny_model()
+    reps = [Replica("pf0", 2.0), Replica("dc0", 1.0), Replica("dc1", 1.0)]
+    engines = {r.name: DecodeEngine(model, params, max_batch=2, max_seq=64,
+                                    name=r.name) for r in reps}
+    roles = {"pf0": "prefill", "dc0": "decode", "dc1": "decode"}
+    srv = FleetServer(reps, engines, max_queue_depth=4)
+    reqs = [Request(rid=i, prompt=[(7 * i + j) % 64 for j in range(3 + 2 * i)],
+                    max_new_tokens=1 + i % 4) for i in range(8)]
+    _, _, ex = srv.dispatcher.dispatch_stream(
+        engines, reqs, [0.0] * 8, max_queue_depth=4, roles=roles,
+        timeline=timeline)
+    per_pos = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        jax.eval_shape(lambda: model.init_cache(1, 1))))
+    return ex, reqs, per_pos
+
+
+def test_finished_requests_release_their_handoffs():
+    """No handoff cache outlives its request: after the stream the executor
+    holds none and its held-bytes counter is back to 0, while its peak is
+    the most that unfinished requests held at once, under the sum of all."""
+    ex, reqs, per_pos = _tiny_disagg_stream()
+    assert all(r.done and len(r.out_tokens) == r.max_new_tokens for r in reqs)
+    assert ex.n_handoffs == 8 and ex.handoffs == {}
+    assert ex.handoff_bytes_held == 0
+    sizes = [length_bucket(len(r.prompt), 64) * per_pos for r in reqs]
+    assert max(sizes) <= ex.handoff_bytes_peak < sum(sizes)
+
+
+def test_decode_kill_reinserts_held_handoffs_then_releases_them():
+    """A decode replica killed mid-stream: its heir re-inserts the handoffs
+    of the unfinished requests, which were still held; every request ends
+    exactly once with the undisturbed stream's tokens, and nothing is held
+    afterwards."""
+    _, want, _ = _tiny_disagg_stream()
+    ex, reqs, _ = _tiny_disagg_stream(
+        timeline=(TimelineEvent(2.0, "kill", "dc0"),))
+    assert [r.out_tokens for r in reqs] == [r.out_tokens for r in want]
+    assert ex.n_handoffs == 8 and ex.handoffs == {}
+    assert ex.handoff_bytes_held == 0 and ex.handoff_bytes_peak > 0
+
+
+def test_cluster_disagg_reports_handoff_bytes():
+    model, params = tiny_model()
+    reqs = [Request(rid=i, prompt=[1 + i, 2, 3, 4][: 2 + i % 3],
+                    max_new_tokens=2 + i % 3) for i in range(6)]
+    rep = Cluster("pf0=2.0^prefill,dc0=1.0x2^decode").serve(
+        ServeJob(reqs, model=model, params=params, max_seq=64))
+    m = rep.metrics
+    assert m["n_handoffs"] == 6
+    assert m["handoff_bytes_peak"] > 0
 
 
 # ================================================================= cluster

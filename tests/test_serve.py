@@ -337,7 +337,8 @@ def test_spans_nest_inside_their_parents(span_runs, kind):
                    for s in tracer.spans}
     assert parent_name["engine.step"] == "runtime.tick"
     assert parent_name["engine.step.fetch"] == "engine.step"
-    assert parent_name["runtime.tick"] == ("serve.wave" if kind == "mixed" else None)
+    # A disaggregated fleet serves the pool as one open-loop stream: one wave.
+    assert parent_name["runtime.tick"] == "serve.wave"
     if kind == "disagg":
         assert parent_name["engine.prefill"] == "runtime.tick"
         assert parent_name["engine.prefill.device"] == "engine.prefill"
@@ -365,6 +366,20 @@ def test_every_request_has_its_wait_spans(span_runs, kind):
     if kind == "mixed":
         # Two requests a replica a wave: the fleet needed several waves.
         assert sum(s.name == "serve.wave" for s in tracer.spans) == 3
+
+
+def test_disagg_spans_carry_handoff_bytes(span_runs):
+    """Each ``engine.prefill`` span names the bytes of the handoff it made,
+    the same per cache position at every bucket; the stream's ``serve.wave``
+    carries the most handoff bytes the executor held at once."""
+    tracer = span_runs["disagg"][0]
+    prefills = [s.attrs for s in tracer.spans if s.name == "engine.prefill"]
+    assert len(prefills) == len(_span_requests())
+    per_pos = {a["handoff_bytes"] / a["bucket"] for a in prefills}
+    assert len(per_pos) == 1 and per_pos.pop() > 0
+    (wave,) = [s.attrs for s in tracer.spans if s.name == "serve.wave"]
+    sizes = [a["handoff_bytes"] for a in prefills]
+    assert max(sizes) <= wave["handoff_bytes_peak"] <= sum(sizes)
 
 
 @pytest.mark.parametrize("kind", SPAN_FLEETS)

@@ -25,6 +25,7 @@ Slow tier: the BENCH_wallclock flow end-to-end, asserting every case's
 """
 
 import json
+import time
 import warnings
 
 import numpy as np
@@ -143,6 +144,27 @@ def test_wallclock_shared_across_jobs():
     assert cluster._wallclock is not None
     assert cluster._wallclock.device_index("w0") == \
         cluster._wallclock.device_index("w0")
+
+
+def test_wallclock_step_clock_starts_afresh_each_job():
+    # A job's step clock is learned from its own ticks: a slow tick of the
+    # last job (a compile, say) does not set the next job's first ticks.
+    class Slow:
+        uniform_cost = 1.0
+
+        def tick(self, worker, now_s):
+            time.sleep(0.05)
+            return []
+
+    backend = WallclockBackend(calibration_reps=4)
+    ex, w = Slow(), SimWorker("w0", 1.0)
+    backend.begin_job(ex, 1, 0.0)
+    backend.timed_tick(ex, w, 0.0)
+    assert backend.step_clock(w) >= 0.05
+    assert backend.tick_s(ex, w, 0.0) == backend.step_clock(w)
+    backend.begin_job(ex, 1, 1.0)
+    assert backend.step_clock(w) == backend.unit_s
+    assert backend.tick_s(ex, w, 1.0) == backend.unit_s
 
 
 def test_wallclock_matmul_values_exact():
