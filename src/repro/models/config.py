@@ -124,9 +124,6 @@ class ModelConfig:
 
     # ---- performance knobs (§Perf iterations) ----
     seq_parallel: bool = False    # shard residual-stream seq dim over `model`
-    decode_sample: bool = False   # decode_step returns argmax tokens, not logits
-                                  # (kills the (B,1,V) gather: argmax reduces
-                                  # over the V-sharded dim on-device)
     ce_chunk: int = 0             # >0: fused chunked cross-entropy (no (B,S,V) live)
     remat_policy: str = "nothing"  # nothing | dots (dots_with_no_batch_dims_saveable)
     cache_dtype: str = ""          # decode cache storage dtype ("" = compute_dtype)

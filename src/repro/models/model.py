@@ -241,7 +241,4 @@ class Model:
             pattern=dec_pattern(cfg),
         )
         x = apply_norm(cfg, params["final_norm"], x)
-        logits = lm_logits(params["embed"], x, cfg)
-        if cfg.decode_sample:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
-        return logits, caches
+        return lm_logits(params["embed"], x, cfg), caches

@@ -10,7 +10,7 @@ right trade for the CPU test harness.
 The *bucketed prefill fast path* (``prefill``/``insert``) consumes a whole
 prompt in one jitted call instead: prompts are right-padded to a power-of-two
 length bucket (one compiled shape per bucket, block sizes from the autotune
-registry via ``kernels/prefill``), the true last-token logits sample the
+registry via ``kernels/prefill``), the true last-token logits give the
 first output token, and the resulting ``KVHandoff`` — request + first token +
 batch-1 cache slice — can be ``insert()``-ed into a free slot of *any*
 engine, including a different replica (prefill/decode disaggregation).
@@ -20,12 +20,13 @@ The engine reports throughput heartbeats which the homogenized dispatcher
 
 With ``tracer`` set (an ``obs.Tracer``; the executor running the engine sets
 it), each call is a span: ``engine.step`` with its phases ``.prep`` (slot
-admission and the input arrays), ``.device`` (the decode program, waited
-for), ``.fetch`` (the logits pulled to the host) and ``.sample`` (the
-per-slot argmax and bookkeeping), and counters ``active``, ``feeding``
-(active slots that consumed a prompt token and sampled nothing),
-``sampled`` and ``max_batch``; ``engine.prefill`` with ``.device`` and
-``.fetch``, and ``handoff_bytes`` (its ``KVHandoff``'s cache);
+admission and the input arrays), ``.device`` (the decode program with its
+greedy argmax, waited for), ``.fetch`` (the sampled tokens pulled to the
+host: one int32 a slot, counted in ``bytes``) and ``.sample`` (the slot
+bookkeeping), and counters ``active``, ``feeding`` (active slots that
+consumed a prompt token and sampled nothing), ``sampled`` and
+``max_batch``; ``engine.prefill`` with ``.device`` and ``.fetch``, and
+``handoff_bytes`` (its ``KVHandoff``'s cache);
 ``engine.insert``.  Admission closes a request's ``request.queue`` span and
 ``insert`` its ``request.handoff``.
 """
@@ -88,6 +89,22 @@ class KVHandoff:
         return sum(x.nbytes for x in jax.tree_util.tree_leaves(self.caches))
 
 
+def greedy_token(logits, vocab_size: int):
+    """The first index of the largest logit over the true vocabulary (the
+    padded tail excluded), as int32: ``logits`` ``(..., padded_vocab)``."""
+    return jnp.argmax(logits[..., :vocab_size], axis=-1).astype(jnp.int32)
+
+
+def decode_step(model: Model, params, caches, toks, pos):
+    """The program ``DecodeEngine.step`` runs: ``model.decode_step`` and each
+    slot's greedy token, so only ``(max_batch,)`` int32 leave the device.
+    Returns ``(tokens, caches)``.  Jitted with ``model`` static and the cache
+    donated (``static_argnums=0, donate_argnums=2``); ``model.decode_step``
+    is looked up as it is traced."""
+    logits, caches = model.decode_step(params, caches, toks, pos)
+    return greedy_token(logits[:, 0], model.cfg.vocab_size), caches
+
+
 # The engine's cache is donated, so an insert writes its lane in place
 # instead of holding a second whole cache while it copies.
 @functools.partial(jax.jit, donate_argnums=0)
@@ -130,7 +147,7 @@ class DecodeEngine:
         self.slots = [_Slot() for _ in range(max_batch)]
         self.queue: list[Request] = []
         self.caches = model.init_cache(max_batch, max_seq)
-        self._decode = jax.jit(model.decode_step, donate_argnums=1)
+        self._decode = jax.jit(decode_step, static_argnums=0, donate_argnums=2)
         self._prefills: dict[int, object] = {}   # bucket -> jitted prefill
         self.steps = 0
         self.tokens_out = 0
@@ -212,22 +229,21 @@ class DecodeEngine:
                 model = self.model
 
                 def run(params, toks, last_pos):
-                    return model.prefill(params, {"tokens": toks},
-                                         last_pos=last_pos)
+                    logits, caches = model.prefill(
+                        params, {"tokens": toks}, last_pos=last_pos)
+                    return greedy_token(logits[0, 0], model.cfg.vocab_size), caches
 
                 fn = jax.jit(run)
                 self._prefills[bucket] = fn
             with span("engine.prefill.device"):
-                logits, caches = fn(
+                token, caches = fn(
                     self.params, jnp.asarray(toks, jnp.int32),
                     jnp.int32(L - 1)
                 )
-                logits.block_until_ready()
+                token.block_until_ready()
             with span("engine.prefill.fetch"):
-                lg = np.asarray(logits[0, 0, : self.model.cfg.vocab_size],
-                                np.float32)
                 first = (
-                    int(lg.argmax()) if self.greedy
+                    int(token) if self.greedy
                     else int(self.rng.choice(self.model.cfg.vocab_size))
                 )
             handoff = KVHandoff(req=req, pos=L, first_token=first,
@@ -317,15 +333,15 @@ class DecodeEngine:
                          max_batch=self.max_batch)
                 return []
             with span("engine.step.device"):
-                logits, self.caches = self._decode(
-                    self.params, self.caches, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(pos, jnp.int32),
+                tokens, self.caches = self._decode(
+                    self.model, self.params, self.caches,
+                    jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
                 )
-                logits.block_until_ready()
+                tokens.block_until_ready()
             self.steps += 1
             with span("engine.step.fetch") as fetch:
-                lg = np.asarray(logits[:, 0], np.float32)
-                fetch.set(bytes=lg.nbytes)
+                tokens = np.asarray(tokens)
+                fetch.set(bytes=tokens.nbytes)
             finished = []
             sampled = 0
             with span("engine.step.sample"):
@@ -340,8 +356,7 @@ class DecodeEngine:
                         if slot.fed < len(r.prompt):
                             continue  # still feeding prompt; no sample yet
                     nxt = (
-                        int(lg[i, : self.model.cfg.vocab_size].argmax())
-                        if self.greedy
+                        int(tokens[i]) if self.greedy
                         else int(self.rng.choice(self.model.cfg.vocab_size))
                     )
                     r.out_tokens.append(nxt)

@@ -24,6 +24,7 @@ from repro.kernels.mamba_scan.ops import ssd
 from repro.kernels.matmul.ops import matmul
 from repro.kernels.prefill.ops import prefill_attention
 from repro.models.model import Model
+from repro.serve.engine import decode_step
 
 import repro.models.attention as attention
 
@@ -98,9 +99,10 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# The decode step at chat engine ``a``'s shape in each chip cell: Qwen2-1.5B
-# whole at 32 slots, and Qwen3-8B (32/8 heads of 128, d 4096, QK-norm) cut
-# to an 18-layer stage at 16 slots; each row is (config, slots, max_seq).
+# The engine's decode step (the model's, and each slot's greedy token) at
+# chat engine ``a``'s shape in each chip cell: Qwen2-1.5B whole at 32 slots,
+# and Qwen3-8B (32/8 heads of 128, d 4096, QK-norm) cut to an 18-layer stage
+# at 16 slots; each row is (config, slots, max_seq).
 DECODE = {
     "qwen2-1.5b": (lambda: get_config("qwen2-1.5b"), 32, 2048),
     "qwen3-8b-18l": (lambda: get_config("qwen3-8b", n_layers=18), 16, 2048),
@@ -121,14 +123,17 @@ def _decode_step(one_chip, name):
                       one_chip)
     toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(model.decode_step, donate_argnums=1).lower(
-        params, caches, toks, pos).compile()
+    compiled = jax.jit(decode_step, static_argnums=0, donate_argnums=2).lower(
+        model, params, caches, toks, pos).compile()
     return compiled, caches
 
 
 @pytest.mark.parametrize("name", DECODE)
 def test_decode_step_compiles_for_v5e(one_chip, name):
     compiled, _ = _decode_step(one_chip, name)
+    tokens, _ = compiled.out_info
+    # Only the greedy token of each slot leaves the program besides the cache.
+    assert (tokens.shape, tokens.dtype) == ((DECODE[name][1],), jnp.int32)
     mem = compiled.memory_analysis()
     # Weights and cache stay within one chip's 16 GB.
     assert mem.argument_size_in_bytes < 16 * 2**30

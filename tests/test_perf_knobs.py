@@ -105,19 +105,6 @@ def test_onehot_cache_update_exact():
     )
 
 
-def test_decode_sample_matches_argmax():
-    cfg = base_cfg()
-    m = Model(cfg)
-    p = m.init(jax.random.key(0))
-    batch = lm_batch()
-    logits = _decode_all(m, p, batch, 13)
-    toks = _decode_all(Model(dataclasses.replace(cfg, decode_sample=True)), p, batch, 13)
-    assert toks.dtype == jnp.int32
-    np.testing.assert_array_equal(
-        np.asarray(toks[:, 0]), np.asarray(jnp.argmax(logits[:, 0], -1))
-    )
-
-
 def test_full_unroll_same_numerics():
     cfg = base_cfg()
     m = Model(cfg)

@@ -1,6 +1,7 @@
 """Serving tests: continuous-batching engine correctness + homogenized dispatch,
 and the serving path's spans."""
 
+import dataclasses
 import glob
 
 import jax
@@ -20,6 +21,7 @@ from repro.serve import (
     Replica,
     Request,
 )
+from repro.serve.engine import greedy_token
 
 
 def tiny_model(moe=False):
@@ -111,6 +113,85 @@ def test_engine_eos_stops():
 
 
 # ------------------------------------------------------------------- dispatch
+def padded_model():
+    """``tiny_model`` with a vocabulary of 61 padded to 64."""
+    m, _ = tiny_model()
+    cfg = dataclasses.replace(m.cfg, vocab_size=61, vocab_pad_to=64)
+    assert cfg.padded_vocab > cfg.vocab_size
+    m = Model(cfg)
+    return m, m.init(jax.random.key(1))
+
+
+def test_greedy_token_skips_the_vocabulary_padding():
+    logits = jnp.asarray([[0.0, 2.0, 2.0, 9.0], [3.0, 1.0, 3.0, 0.0]])
+    tokens = greedy_token(logits, 3)
+    assert tokens.dtype == jnp.int32
+    assert tokens.tolist() == [1, 0]        # the first of equal maxima
+
+
+def test_engine_step_tokens_are_the_host_argmax():
+    """Each step's device tokens are, bitwise, the host argmax over the true
+    vocabulary of ``Model.decode_step``'s logits for the same inputs, in
+    every slot, feeding or sampling; only those int32 are fetched."""
+    model, params = padded_model()
+    vocab = model.cfg.vocab_size
+    eng = DecodeEngine(model, params, max_batch=3, max_seq=32)
+    eng.tracer = Tracer()
+    logits_of = jax.jit(model.decode_step)
+    run = eng._decode
+    host, device = [], []
+
+    def recording(model_, params_, caches, toks, pos):
+        logits, _ = logits_of(params_, jax.tree.map(jnp.copy, caches), toks, pos)
+        host.append(np.asarray(logits, np.float32)[:, 0, :vocab].argmax(-1))
+        tokens, caches = run(model_, params_, caches, toks, pos)
+        device.append(np.asarray(tokens))
+        return tokens, caches
+
+    eng._decode = recording
+    reqs = [Request(rid=i, prompt=[(7 * i + j) % vocab for j in range(1 + 3 * i)],
+                    max_new_tokens=2 + i) for i in range(5)]
+    for r in reqs:
+        eng.submit(r)
+    done = eng.run_until_drained()
+    assert sorted(r.rid for r in done) == list(range(5))
+    assert len(device) == eng.steps > 0
+    for h, d in zip(host, device, strict=True):
+        assert d.dtype == np.int32 and d.shape == (3,)
+        np.testing.assert_array_equal(d, h)
+    steps = [s for s in eng.tracer.spans if s.name == "engine.step"]
+    assert any(s.attrs["feeding"] and s.attrs["sampled"] for s in steps)
+    fetch = [s for s in eng.tracer.spans if s.name == "engine.step.fetch"]
+    assert len(fetch) == eng.steps
+    assert all(s.attrs["bytes"] == 4 * 3 for s in fetch)
+    for r in reqs:
+        assert r.out_tokens == _greedy_reference(model, params, r.prompt,
+                                                 r.max_new_tokens, 32)
+
+
+@pytest.mark.parametrize("length", [5, 8])
+def test_prefill_first_token_is_the_host_argmax(length):
+    """``prefill``'s first token is the host argmax over the true vocabulary
+    of ``Model.prefill``'s logits at ``last_pos``; ``insert`` starts the
+    request from it and decodes on as the full forward does."""
+    model, params = padded_model()
+    vocab = model.cfg.vocab_size
+    prompt = [(5 * j + 3) % vocab for j in range(length)]
+    eng = DecodeEngine(model, params, max_batch=2, max_seq=32)
+    req = Request(rid=0, prompt=prompt, max_new_tokens=4)
+    handoff = eng.prefill(req)
+    toks = np.zeros((1, handoff.bucket), np.int32)
+    toks[0, :length] = prompt
+    logits, _ = model.prefill(params, {"tokens": jnp.asarray(toks)},
+                              last_pos=jnp.int32(length - 1))
+    assert handoff.first_token == int(
+        np.asarray(logits, np.float32)[0, 0, :vocab].argmax())
+    assert eng.insert(handoff) >= 0
+    assert req.out_tokens == [handoff.first_token]
+    eng.run_until_drained()
+    assert req.out_tokens == _greedy_reference(model, params, prompt, 4, 32)
+
+
 def test_dispatch_proportional_after_learning():
     d = HomogenizedDispatcher([Replica("fast", 10.0), Replica("slow", 2.0)])
     res = None
@@ -394,8 +475,8 @@ def test_step_counters_add_up_to_the_decoded_tokens(span_runs, kind):
         a = s.attrs
         assert a["active"] == a["feeding"] + a["sampled"] <= a["max_batch"]
     fetch = [s for s in tracer.spans if s.name == "engine.step.fetch"]
-    vocab = tiny_model()[0].cfg.vocab_size
-    assert fetch and all(s.attrs["bytes"] >= 4 * vocab for s in fetch)
+    max_batch = {s.id: s.attrs["max_batch"] for s in steps}
+    assert fetch and all(s.attrs["bytes"] == 4 * max_batch[s.parent] for s in fetch)
 
 
 @pytest.mark.parametrize("kind", SPAN_FLEETS)
