@@ -1,12 +1,10 @@
-"""Fleet-serving benchmark: batched EngineExecutor path vs per-request-serial.
+"""Fleet-serving benchmark on the batched EngineExecutor path.
 
 Real-model scale (a small but fully compiled decoder, real ``DecodeEngine``
-replicas; ``tests/test_fleet.py`` asserts the same numbers at timing scale
-with stub engines), driven through the declarative Cluster API.  Three
+replicas; ``tests/test_fleet.py`` asserts the same invariants at timing
+scale with stub engines), driven through the declarative Cluster API.  Two
 measurements on the same request distribution:
 
-  serial    one request per grain, each engine drained at grain completion,
-            modeled timing (the pre-EngineExecutor serving path),
   batched   engines as incremental runtime executors: slots stay full,
             durations are measured engine-step counts on each replica's step
             clock, heartbeats are measured tokens/sec,
@@ -14,7 +12,7 @@ measurements on the same request distribution:
             mid-bundle* (``halve:r0@25%``) after a warm wave — the
             homogenization-quality number under mid-bundle degradation.
 
-A fourth measurement exercises the open-loop stack end to end:
+A third measurement exercises the open-loop stack end to end:
 
   sustained  requests *arrive* (Poisson + a burst) instead of being planned
              as waves; full queues shed; the first replica's clock halves
@@ -23,7 +21,7 @@ A fourth measurement exercises the open-loop stack end to end:
              shed rate, goodput under deadline, and the autoscaled
              replica's share of the work.
 
-A fifth compares serving planes on identical hardware and arrivals:
+A fourth compares serving planes on identical hardware and arrivals:
 
   disagg     the same sustained Poisson stream served twice — once by a
              mixed-role fleet (prompts teacher-forced through the decode
@@ -32,14 +30,12 @@ A fifth compares serving planes on identical hardware and arrivals:
              pool (KV handoff insert).  Reports both p99 TTFTs, the TTFT
              split, and handoff counts, with backend provenance.
 
-Acceptance (ISSUE 3): batched >= 2x serial tokens/sec on the same request
-set; fault quality <= 1.3.  Acceptance (ISSUE 6): the sustained entry has
-non-null p50/p99 TTFT, a nonzero shed rate under the Poisson overload, the
-autoscaled join visible in the shares, and survivor quality <= 1.3 under the
-mid-stream halve.  Acceptance (ISSUE 9): the disagg entry beats the mixed
-baseline on p99 TTFT (``p99_ttft_speedup > 1``).  The fleet spec and
-scenario DSL strings ride into the JSON for traceability.  Output:
-``BENCH_serve.json``.
+Acceptance: fault quality <= 1.3.  The sustained entry has non-null
+p50/p99 TTFT, a nonzero shed rate under the Poisson overload, the
+autoscaled join visible in the shares, and survivor quality <= 1.3 under
+the mid-stream halve.  The disagg entry beats the mixed baseline on p99
+TTFT (``p99_ttft_speedup > 1``).  The fleet spec and scenario DSL strings
+ride into the JSON for traceability.  Output: ``BENCH_serve.json``.
 
 Run:   PYTHONPATH=src python -m benchmarks.bench_serve
 Toy:   PYTHONPATH=src python -m benchmarks.bench_serve --requests 12 --max-new 4
@@ -102,16 +98,8 @@ def run_bench(n_requests: int, max_new: int, fleet: FleetSpec | str,
 
     reqs = make_requests(n_requests, vocab, max_new, seed=seed)
     t0 = time.perf_counter()
-    rep = Cluster(fleet).serve(job(reqs, batched=False))
-    out["serial"] = summarize(rep, time.perf_counter() - t0)
-
-    reqs = make_requests(n_requests, vocab, max_new, seed=seed)
-    t0 = time.perf_counter()
     rep = Cluster(fleet).serve(job(reqs))
     out["batched"] = summarize(rep, time.perf_counter() - t0)
-    out["speedup"] = (
-        out["batched"]["tokens_per_s"] / out["serial"]["tokens_per_s"]
-    )
 
     # Mid-bundle perf-halving: warm wave teaches the tracker the true rates,
     # then r0's step clock halves 25% into the measured wave.
@@ -234,10 +222,8 @@ def main(argv: list[str] | None = None) -> dict:
                        args.queue_depth)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=2)
-    print(f"serial : {result['serial']['tokens_per_s']:8.2f} tok/s "
-          f"(modeled timing, engines drained per request)")
     print(f"batched: {result['batched']['tokens_per_s']:8.2f} tok/s "
-          f"(measured engine clocks) -> speedup {result['speedup']:.2f}x")
+          f"(measured engine clocks)")
     print(f"fault  : {result['fault']['tokens_per_s']:8.2f} tok/s with "
           f"[{result['fault']['scenario']}] mid-bundle, quality "
           f"{result['fault']['worst_quality']:.2f}, "

@@ -8,8 +8,8 @@ replaced immediately.
 Part 2 — batched fleet serving: three replicas of unequal step clocks *and*
 slot counts described by one ``FleetSpec`` string.  Engines are first-class
 runtime executors: slots stay full, durations are measured engine-step
-counts, heartbeats are measured tokens/sec.  The same request set through the
-per-request-serial path shows what slot-level batching buys.
+counts, heartbeats are measured tokens/sec, and the shares follow each
+replica's slots times its step clock.
 
 Part 3 — the tentpole scenario on real engines, scripted in the Scenario DSL:
 ``halve:r-fast@20%`` halves a replica's step clock mid-bundle.  The static
@@ -82,7 +82,7 @@ def main() -> None:
     print(f"engine steps={eng.steps} tokens_out={eng.tokens_out} "
           f"(tokens/step={eng.throughput:.2f} — continuous batching keeps slots busy)")
 
-    # ------------- Part 2: batched fleet vs per-request-serial --------------
+    # ------------------------ Part 2: batched fleet serving ----------------
     print(f"\n== batched fleet serving (fleet: {FLEET}) ==")
 
     def job(reqs, **kw):
@@ -91,14 +91,9 @@ def main() -> None:
         return ServeJob(reqs, model=model, params=params, max_seq=64,
                         max_queue_depth=kw.pop("max_queue_depth", 16), **kw)
 
-    serial = Cluster(FLEET).serve(job(mk_requests(24), batched=False))
     batched = Cluster(FLEET).serve(job(mk_requests(24)))
-    print(f"serial : {serial.throughput:7.2f} tok/s "
-          f"(one request per grain, engines drained at completion)")
     print(f"batched: {batched.throughput:7.2f} tok/s  shares="
           f"{dict(batched.phases[0].shares)}")
-    print(f"slot-level continuous batching buys "
-          f"{batched.throughput / serial.throughput:.2f}x fleet tokens/sec")
 
     # -------- Part 3: mid-bundle degradation, adaptive vs static ------------
     print("\n== r-fast's step clock halves mid-bundle (48 requests) ==")

@@ -64,7 +64,7 @@ import numpy as np
 from ..coord import CoordSpec, ShardedCoordinator
 from ..core.homogenization import OverheadModel, predicted_speedup, scope_lengths
 from ..core.performance import PerformanceTracker
-from ..core.runtime import AsyncRuntime, ExecutionBackend, SimBackend, SimWorker
+from ..core.runtime import AsyncRuntime, ExecutionBackend, SimWorker
 from ..core.simulate import ClusterSim
 from ..obs import Tracer
 from .profiles import DEFAULT_PROFILE, select_profile
@@ -133,7 +133,6 @@ class ServeJob:
     engine_factory: Callable[[WorkerSpec], Any] | None = None
     max_seq: int = 64
     max_queue_depth: int = 8
-    batched: bool = True
     fresh: bool = False          # force a new fleet server (fresh engines + tracker)
     # Open-loop knobs (used when the scenario has workload clauses —
     # ``arrive:``/``burst:``/``mix:``/``scale:``; ignored in wave mode):
@@ -255,9 +254,8 @@ class Cluster:
         """True when grain durations are measured (not the sim clock)."""
         b = self._wallclock
         if b is None:
-            return not isinstance(self.backend, str) or \
-                self.backend == "wallclock"
-        return type(b) not in (SimBackend, ExecutionBackend)
+            return self.backend == "wallclock"
+        return b.measured
 
     def _backend_label(self) -> str:
         """RunReport provenance: 'sim' or '<name>[<n>d]' for measured
@@ -699,10 +697,9 @@ class Cluster:
               scenario: Scenario | str | None = None) -> RunReport:
         """Serve ``job.requests`` over this fleet's engines in
         admission-controlled waves.  The fleet server (engines + learned
-        tracker state) persists across calls — warm-up traffic teaches the
-        dispatcher measured rates, exactly like production."""
-        from ..serve.dispatch import Replica
-        from ..serve.fleet import FleetServer
+        tracker state) persists across calls — warm-up traffic teaches its
+        runtime measured rates, exactly like production."""
+        from ..serve.fleet import FleetServer, Replica
 
         sc = Scenario.parse(scenario)
         if sc.jitter:
@@ -751,15 +748,14 @@ class Cluster:
                 engines,
                 max_queue_depth=job.max_queue_depth,
                 homogenize=self.homogenize,
+                adaptive=self.adaptive,
+                replan_threshold=self.replan_threshold,
                 engine_factory=self._engine_for_worker,
                 authority=self._new_authority(),
                 backend=self._new_backend(),
                 eta_mode=self.eta_mode,
                 tracer=self.tracer,
             )
-            server.dispatcher.runtime.rehomogenize = self._rehomogenize
-            server.dispatcher.runtime.steal = self._rehomogenize
-            server.dispatcher.runtime.replan_threshold = self.replan_threshold
             if self.priors == "spec":
                 self._spec_priors(server.tracker, rate=True)
             self._server = server
@@ -805,8 +801,7 @@ class Cluster:
                 for ev in sched.phase_events(wave_idx, 0.0)
             )
 
-        rep = server.serve(requests, timeline_fn=wave_events,
-                           batched=job.batched)
+        rep = server.serve(requests, timeline_fn=wave_events)
 
         phases, spans = [], []
         elapsed = 0.0
@@ -833,8 +828,7 @@ class Cluster:
                 (server.tracker.perf(w) for w in live), default=0.0)
             meas = (cost / max(r_meas, _EPS)) / max(rep.sim_time_s, _EPS)
         self._autoselect_profiles(server.tracker, per_slot=True)
-        metrics = {"n_requests": rep.n_requests, "batched": job.batched,
-                   "n_waves": len(rep.bundles)}
+        metrics = {"n_requests": rep.n_requests, "n_waves": len(rep.bundles)}
         if self._auto_profiles:
             metrics["auto_profiles"] = dict(self._auto_profiles)
         return RunReport(
@@ -844,8 +838,7 @@ class Cluster:
             predicted_speedup=pred, measured_speedup=meas,
             worker_timelines=merge_worker_timelines(spans),
             metrics=metrics,
-            artifact=requests, coord=self._coord_stats(
-                server.dispatcher.runtime),
+            artifact=requests, coord=self._coord_stats(server.runtime),
             backend=self._backend_label(), telemetry=self._telemetry(),
         )
 
@@ -891,7 +884,7 @@ class Cluster:
         ``roles`` (worker -> 'prefill'|'decode', from a roled FleetSpec)
         switches the stream to the disaggregated plane; the report's metrics
         then carry the TTFT split, per-role quality and handoff count."""
-        from ..serve.dispatch import Replica
+        from ..serve.fleet import Replica
         from .workload import materialize_workload
 
         requests = list(job.requests)
@@ -1030,7 +1023,7 @@ class Cluster:
             predicted_speedup=pred, measured_speedup=meas,
             worker_timelines=merge_worker_timelines(spans),
             metrics=metrics, artifact=used,
-            coord=self._coord_stats(server.dispatcher.runtime),
+            coord=self._coord_stats(server.runtime),
             latency=lat, backend=self._backend_label(),
             telemetry=self._telemetry(),
         )

@@ -34,8 +34,8 @@ for it, and what real compute happens at completion (never for aborted
 grains), so values are exact while timing comes from the cost model.  Sim
 row-blocks, serve request bundles and HDP training microbatches are three
 executors of the same loop.  ``TDAServer``/``ThinClient``,
-``HomogenizedDispatcher``, ``ClusterSim``, ``HDPTrainer`` and ``ElasticFleet``
-are all thin clients.
+``FleetServer``, ``ClusterSim``, ``HDPTrainer`` and ``ElasticFleet`` are all
+thin clients.
 
 *Who decides* is the ``DispatchAuthority`` seam: heartbeat ingest, mid-job
 re-homogenization, stealing and kill-heir choice route through one authority
@@ -310,9 +310,15 @@ class ExecutionBackend:
     and ``timed_tick`` wraps the executor's real step so a measuring backend
     can time it.  ``begin_job``/``end_job``/``stats`` bracket one job and
     surface backend provenance on ``RuntimeResult.backend``.
+
+    ``measured`` says whether durations are wall-clock observations.  A
+    measuring backend also gives ``step_clock(worker)``, the measured
+    seconds per engine step, which the serving layer wires into its
+    executors' heartbeats and tick schedule.
     """
 
     name = "sim"
+    measured = False
     runtime: "AsyncRuntime | None" = None
 
     def bind(self, runtime: "AsyncRuntime") -> None:
@@ -875,7 +881,7 @@ class AsyncRuntime:
         backend = self.backend
         # The sim default keeps the exact pre-seam call sequence (no per-event
         # backend indirection): bitwise-identical results, identical hot path.
-        sim_exec = type(backend) in (SimBackend, ExecutionBackend)
+        sim_exec = not backend.measured
         # Same idiom for tracing: one local, one None-check per emit site.
         tracer = self.tracer
         pooled = executor.pooled

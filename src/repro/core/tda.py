@@ -29,13 +29,7 @@ from typing import Callable
 import numpy as np
 
 from .performance import PerformanceTracker, PerfReport
-from .runtime import (
-    AsyncRuntime,
-    ExecutionBackend,
-    RuntimeResult,
-    SimBackend,
-    TimelineEvent,
-)
+from .runtime import AsyncRuntime, RuntimeResult, TimelineEvent
 from .scheduler import GrainPlan, HomogenizedScheduler
 from .simulate import ClusterSim
 
@@ -169,9 +163,7 @@ class ThinClient:
             eta_mode=eta_mode,
             backend=backend,
         )
-        self._measured = backend is not None and type(backend) not in (
-            SimBackend, ExecutionBackend
-        )
+        self._measured = self.runtime.backend.measured
         self.last_result: RuntimeResult | None = None
 
     def matmul(

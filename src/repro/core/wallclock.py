@@ -104,6 +104,7 @@ class WallclockBackend(ExecutionBackend):
     """
 
     name = "wallclock"
+    measured = True
 
     def __init__(
         self,
@@ -198,10 +199,11 @@ class WallclockBackend(ExecutionBackend):
     def step_clock(self, worker: Any) -> float:
         """Measured wall seconds per engine step for ``worker`` (EMA over
         this job's ``timed_tick``), seeded at the calibrated unit time until
-        the job's first real tick lands — never the modeled ``1/perf`` clock,
-        which is on a different (simulated-seconds) scale entirely.  Wired into
-        ``EngineExecutor.step_clock`` so serve heartbeats report measured
-        tokens/sec."""
+        the job's first real tick lands: one engine step is one real jitted
+        call, the same order of work as a unit op.  Never the modeled
+        ``1/perf`` clock, which is on a different (simulated-seconds) scale
+        entirely.  Wired into ``EngineExecutor.step_clock``, so the runtime's
+        tick schedule and the serve heartbeats both read it."""
         return self._tick_ema.get(getattr(worker, "name", ""), self._unit_s)
 
     # -- device assignment ---------------------------------------------------
@@ -289,13 +291,6 @@ class WallclockBackend(ExecutionBackend):
         return elapsed_s
 
     # -- ExecutionBackend: incremental (engine) grains ----------------------
-    def tick_s(self, executor: GrainExecutor, worker: Any,
-               now_s: float) -> float:
-        # Seed unmeasured workers at the calibrated unit time: one engine
-        # step is one real jitted call, the same order of work as a unit op.
-        # The modeled executor.tick_s is simulated seconds — wrong scale.
-        return self._tick_ema.get(worker.name, self._unit_s)
-
     def timed_tick(self, executor: GrainExecutor, worker: Any,
                    now_s: float) -> list[tuple[int, Any]]:
         t0 = time.perf_counter()

@@ -1,5 +1,5 @@
 """Serving launcher: a real continuous-batching engine fleet behind the
-homogenized dispatcher, driven through the declarative Cluster API.
+homogenizing fleet server, driven through the declarative Cluster API.
 
 ``--fleet`` is the ``FleetSpec`` grammar (``[NAME=]PERFxSLOTS[@PROFILE]``,
 comma- or colon-separated — the old ``--replicas PERFxBATCH`` grammar is a
@@ -12,7 +12,7 @@ requests migrate off degrading replicas, and joined replicas lazily bring
 their engines.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
-      --requests 24 --fleet 8x4:4x2:2x1 --scenario halving --compare-serial
+      --requests 24 --fleet 8x4:4x2:2x1 --scenario halving
 
 Workload clauses (``arrive:``/``burst:``/``mix:``/``scale:``) switch the run
 open-loop: requests *arrive* on the scenario's schedule, full queues shed or
@@ -68,23 +68,6 @@ def parse_replicas(spec: str) -> list[tuple[float, int]]:
     return [(w.perf, w.concurrency) for w in fleet.workers]
 
 
-def build_fleet(model, params, specs, max_seq: int, queue_depth: int):
-    """Deprecated shim for the pre-Cluster entry point: builds the legacy
-    ``FleetServer`` (old callers, benchmarks at timing scale).  New code
-    should use ``Cluster(fleet).serve(ServeJob(...))``."""
-    from ..serve.dispatch import Replica
-    from ..serve.engine import DecodeEngine
-    from ..serve.fleet import FleetServer
-
-    replicas = [Replica(f"r{i}", p) for i, (p, _) in enumerate(specs)]
-    engines = {
-        f"r{i}": DecodeEngine(model, params, max_batch=b, max_seq=max_seq,
-                              name=f"r{i}")
-        for i, (_, b) in enumerate(specs)
-    }
-    return FleetServer(replicas, engines, max_queue_depth=queue_depth)
-
-
 def make_requests(n: int, vocab: int, max_new: int, seed: int = 0):
     rng = np.random.default_rng(seed)
     return [
@@ -127,9 +110,6 @@ def main() -> None:
                     help="'none'|'halving'|'kill' (legacy names, fault 25%% "
                          "into the first wave) or a Scenario DSL string, e.g. "
                          "'halve:r0@25%%;join:r3=4x2@80%%'")
-    ap.add_argument("--compare-serial", action="store_true",
-                    help="also run the per-request-serial baseline on a "
-                         "fresh fleet and report the batched speedup")
     ap.add_argument("--overflow", choices=("queue", "shed"), default="queue",
                     help="open-loop admission when every replica queue is "
                          "full: backlog the arrival or shed it (reject trace)")
@@ -248,16 +228,6 @@ def main() -> None:
             json.dump(payload, fh, indent=2)
         print(f"wrote {args.json}")
     export_trace(tracer, args)
-
-    if args.compare_serial:
-        serial = Cluster(fleet, backend=args.backend).serve(
-            ServeJob(make_requests(args.requests, cfg.vocab_size, args.max_new),
-                     model=model, params=params, max_seq=args.max_seq,
-                     max_queue_depth=args.queue_depth, batched=False),
-            scenario=scenario,
-        )
-        print(f"serial baseline: {serial.throughput:.2f} tok/s -> batched "
-              f"speedup {rep.throughput / serial.throughput:.2f}x")
 
 
 if __name__ == "__main__":

@@ -1,5 +1,4 @@
 from .disagg import DisaggExecutor, RoleStats, TTFTSplit
-from .dispatch import DispatchResult, HomogenizedDispatcher, Replica
 from .engine import DecodeEngine, KVHandoff, Request
 from .executor import EngineExecutor
 from .fleet import (
@@ -7,14 +6,13 @@ from .fleet import (
     FleetReport,
     FleetServer,
     LatencyStats,
+    Replica,
     RequestTrace,
     StreamReport,
 )
 
 __all__ = [
     "DisaggExecutor",
-    "DispatchResult",
-    "HomogenizedDispatcher",
     "Replica",
     "RoleStats",
     "TTFTSplit",

@@ -15,8 +15,8 @@ first output token, and the resulting ``KVHandoff`` — request + first token +
 batch-1 cache slice — can be ``insert()``-ed into a free slot of *any*
 engine, including a different replica (prefill/decode disaggregation).
 
-The engine reports throughput heartbeats which the homogenized dispatcher
-(dispatch.py) consumes for cross-replica scope-length allotment.
+The engine reports throughput heartbeats which the fleet server's runtime
+(fleet.py) consumes for cross-replica scope-length allotment.
 
 With ``tracer`` set (an ``obs.Tracer``; the executor running the engine sets
 it), each call is a span: ``engine.step`` with its phases ``.prep`` (slot
@@ -389,7 +389,7 @@ class DecodeEngine:
 
     def heartbeat(self, now_s: float, seconds_per_step: float = 1.0) -> PerfReport | None:
         """Work/sec since the last heartbeat, as a PerfReport for the
-        homogenized dispatcher's tracker (the paper's background process).
+        fleet server's tracker (the paper's background process).
 
         Work counts *prompt tokens consumed* as well as output tokens: a
         step spent teacher-forcing a prompt is real engine work, so a

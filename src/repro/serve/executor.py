@@ -1,10 +1,7 @@
 """EngineExecutor: continuous-batching engines as first-class runtime executors.
 
-The serial real-execution path ran one request per grain and drained the
-engine at grain-completion time, so an engine's ``max_batch`` slots never
-held more than one live request and engine compute never overlapped
-dispatch.  This executor plugs a fleet of ``DecodeEngine`` replicas into the
-async runtime's *incremental* seam instead:
+This executor plugs a fleet of ``DecodeEngine`` replicas into the async
+runtime's *incremental* seam:
 
   - each replica holds up to ``max_batch`` grains in flight (its slots): the
     runtime admits a replica's assigned requests as a bundle and keeps the
@@ -56,13 +53,13 @@ class EngineExecutor(GrainExecutor):
     incremental = True
     uniform_cost = None
     # Optional measured step clock: ``step_clock(worker) -> seconds/step``.
-    # A wall-clock backend wires this to its per-worker tick EMA so
+    # The fleet server wires a measured backend's clock here, so ticks and
     # heartbeats report *measured* tokens/sec instead of the modeled
     # ``1 / perf`` profile.  None keeps the modeled clock.
     step_clock = None
     def __init__(self, engines: Mapping[str, object], requests: Sequence,
                  engine_factory=None, on_finish=None, tracer=None):
-        # Serve-plane tracing (obs.Tracer), passed by the dispatcher:
+        # Serve-plane tracing (obs.Tracer), passed by the fleet server:
         # first_token / ttft_drop / request_done events are *the* carrier
         # for per-request latency — serve_stream folds them back into
         # RequestTraces.  Every engine this executor runs traces its spans

@@ -1,22 +1,23 @@
 """FleetServer: multi-bundle serving over a fleet of real decode engines.
 
 The production face of the serving tier: N heterogeneous replicas (distinct
-``max_batch`` slot counts and step clocks), one homogenized dispatcher, and a
-workload of many requests served back-to-back with **admission control** —
-each wave admits at most ``max_queue_depth`` unstarted requests per live
-replica, the rest wait in the server backlog.  Bounding the per-replica queue
-keeps requests runtime-side (hence migratable off a degrading replica) and
-keeps one replica's death from orphaning a deep queue.
+``max_batch`` slot counts and step clocks), one ``AsyncRuntime`` with its
+tracker, and a workload of many requests served back-to-back with
+**admission control** — each wave admits at most ``max_queue_depth``
+unstarted requests per live replica, the rest wait in the server backlog.
+Bounding the per-replica queue keeps requests runtime-side (hence migratable
+off a degrading replica) and keeps one replica's death from orphaning a deep
+queue.
 
-Each wave is one batched ``dispatch_to_engines`` bundle: engine slots stay
+Each wave is one runtime job over an ``EngineExecutor``: engine slots stay
 full (continuous batching), tokens/sec heartbeats are measured, and the
 tracker state persists across waves, so wave k+1's allotment reflects what
 wave k actually observed.  Timeline events passed to ``serve`` are relative
 to its start; events landing past a wave's end carry over to the next wave
 (the runtime's pending-event semantics).
 
-With a tracer on the dispatcher's runtime, each wave is a ``serve.wave``
-span (an open-loop stream is one wave; a disaggregated one's span carries
+With a tracer on the runtime, each wave is a ``serve.wave`` span (an
+open-loop stream is one wave; a disaggregated one's span carries
 ``handoff_bytes_peak``), and each request's wait before it reaches an engine
 is two request spans: ``request.backlog`` while it waits for its wave,
 ``request.queue`` from its wave's dispatch (or, in an open-loop stream, the
@@ -31,20 +32,27 @@ from collections import deque
 from typing import Sequence
 
 from ..core.homogenization import scope_lengths
-from ..core.runtime import TimelineEvent
+from ..core.performance import PerformanceTracker
+from ..core.runtime import AsyncRuntime, RuntimeResult, SimWorker, TimelineEvent
 from ..core.scheduler import GrainPlan
 from ..obs import NO_SPAN, EventTracer
-from .disagg import RoleStats, TTFTSplit, build_ttft_split
-from .dispatch import HomogenizedDispatcher, Replica
+from .disagg import DisaggExecutor, RoleStats, TTFTSplit, build_ttft_split
+from .executor import EngineExecutor
 
 __all__ = [
     "BundleStats",
     "FleetReport",
     "FleetServer",
     "LatencyStats",
+    "Replica",
     "RequestTrace",
     "StreamReport",
 ]
+
+#: A serving replica is a runtime worker: a name and its true step clock
+#: (``perf``, engine steps per simulated second), hidden from the scheduler,
+#: which learns it from the engines' heartbeats.
+Replica = SimWorker
 
 
 def _percentile(sorted_vals: Sequence[float], q: float) -> float:
@@ -204,7 +212,8 @@ class FleetServer:
     ``replicas[i].perf`` is the replica's step clock (engine steps per
     simulated second); ``engines[name]`` backs each replica with a
     ``DecodeEngine`` (or duck-typed equivalent).  One FleetServer owns one
-    dispatcher/tracker, so learned perfs persist across ``serve`` calls.
+    ``AsyncRuntime`` and its tracker, so learned perfs persist across
+    ``serve`` calls; ``runtime.workers`` is the live fleet.
     """
 
     def __init__(
@@ -214,6 +223,8 @@ class FleetServer:
         *,
         max_queue_depth: int = 8,
         homogenize: bool = True,
+        adaptive: bool = True,
+        replan_threshold: float = 0.05,
         alpha: float = 0.5,
         engine_factory=None,
         authority=None,
@@ -226,9 +237,24 @@ class FleetServer:
         missing = {r.name for r in replicas} - set(engines)
         if missing and engine_factory is None:
             raise ValueError(f"replicas without engines {sorted(missing)}")
-        self.dispatcher = HomogenizedDispatcher(
-            replicas, homogenize=homogenize, alpha=alpha, authority=authority,
-            backend=backend, eta_mode=eta_mode, tracer=tracer,
+        # ``adaptive`` lets the runtime move unstarted requests mid-wave
+        # (re-homogenization and stealing).  ``authority`` shards the
+        # dispatch plane (coord.ShardedCoordinator).  ``backend`` None keeps
+        # the modeled step clock; a measuring one times each engine step.
+        # ``tracer`` (obs.Tracer) observes the runtime; serve_stream attaches
+        # an ephemeral one for its own events when there is none.
+        self.tracker = PerformanceTracker(alpha=alpha, dead_after_s=1e9)
+        self.runtime = AsyncRuntime(
+            list(replicas),
+            tracker=self.tracker,
+            homogenize=homogenize,
+            rehomogenize=homogenize and adaptive,
+            steal=homogenize and adaptive,
+            replan_threshold=replan_threshold,
+            authority=authority,
+            eta_mode=eta_mode,
+            backend=backend,
+            tracer=tracer,
         )
         self.engines = dict(engines)
         self.max_queue_depth = max_queue_depth
@@ -238,10 +264,6 @@ class FleetServer:
         # WorkerSpec always brings (or lazily constructs) its engine before
         # admission — the ROADMAP join fix.
         self.engine_factory = engine_factory
-
-    @property
-    def tracker(self):
-        return self.dispatcher.tracker
 
     def live_replicas(self) -> list[str]:
         if self.engine_factory is not None:
@@ -255,17 +277,50 @@ class FleetServer:
         self.engines[worker.name] = eng
         return eng
 
+    def _executor(self, requests: list, roles: dict[str, str] | None = None,
+                  on_finish=None) -> EngineExecutor | DisaggExecutor:
+        """One job's executor over the live replicas' engines: the
+        disaggregated one when ``roles`` is given.  This is the one place a
+        measuring backend's step clock is wired in, so the tick schedule and
+        the heartbeats read measured seconds per step."""
+        engines = {n: self.engines[n] for n in self.live_replicas()
+                   if n in self.engines}
+        factory = self._factory if self.engine_factory is not None else None
+        unbacked = set(self.tracker.workers()) - set(engines)
+        if unbacked and factory is None:
+            # A live replica with no engine would be scheduled grains it
+            # cannot execute (KeyError mid-wave after partial decode).
+            raise ValueError(f"live replicas without engines {sorted(unbacked)}")
+        tracer = self.runtime.tracer
+        if roles:
+            executor = DisaggExecutor(engines, requests, roles,
+                                      engine_factory=factory,
+                                      on_finish=on_finish, tracer=tracer)
+        else:
+            executor = EngineExecutor(engines, requests,
+                                      engine_factory=factory,
+                                      on_finish=on_finish, tracer=tracer)
+        backend = self.runtime.backend
+        if backend.measured:
+            executor.step_clock = backend.step_clock
+        return executor
+
+    def _shares_quality(self, run: RuntimeResult) -> tuple[dict[str, int], float]:
+        """Grains per replica the tracker knows (0 for one that served
+        nothing), and the drain-time spread over those replicas."""
+        names = self.tracker.workers()
+        counts = run.shares()
+        return ({n: counts.get(n, 0) for n in names},
+                run.homogenization_quality(names))
+
     def serve(
         self,
         requests: Sequence,
         timeline: tuple[TimelineEvent, ...] = (),
-        batched: bool = True,
         timeline_fn=None,
     ) -> FleetReport:
         """Serve ``requests`` in admission-controlled waves; returns per-wave
-        and aggregate measured throughput.  ``batched=False`` routes every
-        wave through the per-request-serial baseline instead (same admission
-        control, no slot-level batching) — the benchmark's comparison axis.
+        and aggregate measured throughput.
 
         ``timeline_fn(wave_idx) -> events`` is the *wave-start callback*
         form: called as each wave actually begins, returning that wave's
@@ -275,7 +330,7 @@ class FleetServer:
         if timeline_fn is not None and timeline:
             raise ValueError("pass either timeline or timeline_fn, not both")
         backlog = deque(requests)
-        tracer = self.dispatcher.runtime.tracer
+        tracer = self.runtime.tracer
         span = NO_SPAN if tracer is None else tracer.span
         if tracer is not None:
             for r in backlog:
@@ -300,33 +355,30 @@ class FleetServer:
                     tracer.close("request.backlog", r.rid)
                     tracer.open("request.queue", r.rid)
             with span("serve.wave", wave=wave_idx, n_requests=len(wave)):
-                res, run = self.dispatcher.dispatch_to_engines(
-                    {n: self.engines[n] for n in live if n in self.engines},
-                    wave,
-                    timeline=wave_timeline,
-                    batched=batched,
-                    engine_factory=(
-                        self._factory if self.engine_factory is not None
-                        else None
-                    ),
-                    initial_plan=self._wave_plan(len(wave)),
+                plan = self._wave_plan(len(wave))
+                run = self.runtime.run(
+                    len(wave),
+                    executor=self._executor(wave),
+                    timeline=wave_timeline, timeline_relative=True,
+                    initial_plan=plan,
                 )
             first = False
             wave_idx += 1
             tokens = sum(len(r.out_tokens) for r in wave)
-            wave_start = run.end_s - run.makespan if run is not None else 0.0
+            wave_start = run.end_s - run.makespan
+            shares, quality = self._shares_quality(run)
             bundles.append(BundleStats(
                 n_requests=len(wave),
                 tokens_out=tokens,
-                sim_time_s=res.makespan,
-                tokens_per_s=tokens / max(res.makespan, 1e-12),
-                quality=res.quality,
-                n_migrated=res.n_migrated,
-                shares=res.shares,
-                worker_busy=dict(run.worker_busy) if run is not None else {},
+                sim_time_s=run.makespan,
+                tokens_per_s=tokens / max(run.makespan, 1e-12),
+                quality=quality,
+                n_migrated=run.n_migrated,
+                shares=shares,
+                worker_busy=dict(run.worker_busy),
                 worker_finish={
                     w: f - wave_start for w, f in run.worker_finish.items()
-                } if run is not None else {},
+                },
             ))
         total_tokens = sum(b.tokens_out for b in bundles)
         total_time = sum(b.sim_time_s for b in bundles)
@@ -347,11 +399,11 @@ class FleetServer:
         the wave and start it depth-deep — exactly the unbounded-queue risk
         admission control exists to prevent.  Returns None when no cap binds,
         which keeps the uncapped path (and its plans) bitwise-identical."""
-        plan = self.dispatcher.runtime.plan(n)
+        plan = self.runtime.plan(n)
         cap = self.max_queue_depth
         if all(s <= cap for s in plan.shares):
             return None
-        now = self.dispatcher.clock
+        now = self.runtime.clock
         capped: dict[str, int] = {}
         free = dict(zip(plan.workers, plan.shares))
         while True:
@@ -430,13 +482,12 @@ class FleetServer:
                 "engine_factory to build their engines; construct the "
                 "FleetServer with engine_factory= (or drop the scale: clause)"
             )
-        live = self.live_replicas()
-        if not live:
+        if not self.live_replicas():
             raise RuntimeError(
                 f"no live replicas; {len(requests)} requests stranded"
             )
 
-        rt = self.dispatcher.runtime
+        rt = self.runtime
         start = rt.clock
         # Per-request TTFT/completion accounting rides the obs event
         # vocabulary: first_token / ttft_drop events from the executor and
@@ -459,8 +510,7 @@ class FleetServer:
         )
 
         def default_scale_worker(i: int) -> Replica:
-            fastest = max(self.dispatcher.replicas.values(),
-                          key=lambda r: r.perf)
+            fastest = max(rt.workers.values(), key=lambda r: r.perf)
             return Replica(f"scale{i}", fastest.perf)
 
         def on_finish(g, req, wname, now_s, first_token_s):
@@ -488,19 +538,9 @@ class FleetServer:
         try:
             with stream_tracer.span("serve.wave", wave=0,
                                     n_requests=len(requests)) as wave:
-                res, run, executor = self.dispatcher.dispatch_stream(
-                    {n: self.engines[n] for n in live if n in self.engines},
-                    requests,
-                    arrive,
-                    timeline=timeline,
-                    max_queue_depth=self.max_queue_depth,
-                    overflow=overflow,
-                    engine_factory=(
-                        self._factory if self.engine_factory is not None
-                        else None
-                    ),
-                    on_finish=on_finish,
-                    roles=roles,
+                run, executor = self._stream(
+                    requests, arrive, timeline=timeline, overflow=overflow,
+                    on_finish=on_finish, roles=roles,
                 )
                 if roles:
                     wave.set(handoff_bytes_peak=executor.handoff_bytes_peak)
@@ -544,6 +584,7 @@ class FleetServer:
             ))
         tokens = sum(t.tokens for t in traces)
         stream_start = run.end_s - run.makespan
+        shares, quality = self._shares_quality(run)
 
         ttft_split: TTFTSplit | None = None
         role_stats: tuple[RoleStats, ...] = ()
@@ -578,9 +619,9 @@ class FleetServer:
             tokens_out=tokens,
             sim_time_s=run.makespan,
             tokens_per_s=tokens / max(run.makespan, 1e-12),
-            quality=res.quality,
+            quality=quality,
             n_migrated=run.n_migrated,
-            shares=res.shares,
+            shares=shares,
             traces=tuple(traces),
             latency=LatencyStats.from_traces(
                 traces, run.makespan, deadline_s=deadline_s),
@@ -595,12 +636,54 @@ class FleetServer:
             handoff_bytes_peak=peak,
         )
 
+    def _stream(
+        self,
+        requests: list,
+        arrive: Sequence[float],
+        *,
+        timeline: tuple[TimelineEvent, ...] = (),
+        overflow: str = "queue",
+        on_finish=None,
+        roles: dict[str, str] | None = None,
+    ) -> tuple[RuntimeResult, EngineExecutor | DisaggExecutor]:
+        """One open-loop runtime job: request ``i`` arrives ``arrive[i]``
+        seconds in and is admitted to the min-ETA replica with queue room
+        (``max_queue_depth``); saturation queues or sheds per ``overflow``.
+        With ``roles`` each request is a prefill grain plus a *deferred*
+        decode grain (its KV handoff), and the pools are homogenized apart.
+        Returns the run and the executor (first-token times, handoffs)."""
+        executor = self._executor(requests, roles=roles, on_finish=on_finish)
+        n = len(requests)
+        run = self.runtime.run(
+            2 * n if roles else n,
+            executor=executor,
+            timeline=timeline, timeline_relative=True,
+            arrivals=[float(t) for t in arrive],
+            n_deferred=n if roles else 0,
+            max_queue_depth=self.max_queue_depth,
+            overflow=overflow,
+        )
+        return run, executor
+
     # -- fleet management (between waves) ------------------------------------
     def degrade(self, name: str, perf: float) -> None:
-        self.dispatcher.degrade(name, perf)
+        """True-perf shift between waves (the tracker learns it from the
+        next wave's heartbeats).  Degrading an unknown or dead replica fails
+        loudly instead of silently mutating a ghost."""
+        worker = self.runtime.workers.get(name)
+        if worker is None:
+            raise KeyError(
+                f"unknown or dead replica {name!r} (kills are sticky; "
+                "rejoin it first)"
+            )
+        worker.perf = perf
 
     def kill(self, name: str) -> None:
-        self.dispatcher.kill(name)
+        """Between-wave kill: the runtime drops the replica and the tracker
+        marks it dead, so no late heartbeat revives it."""
+        if name not in self.runtime.workers:
+            raise KeyError(f"unknown replica {name!r}")
+        self.runtime.remove_worker(name)
 
     def rejoin(self, replica: Replica, engine: object,
                perf_prior: float | None = None) -> None:
@@ -609,5 +692,4 @@ class FleetServer:
         if engine.active or engine.queue:
             raise ValueError(f"engine for {replica.name!r} is not idle")
         self.engines[replica.name] = engine
-        self.dispatcher.runtime.add_worker(replica, perf_prior=perf_prior)
-        self.dispatcher._sync_replicas()
+        self.runtime.add_worker(replica, perf_prior=perf_prior)

@@ -2,16 +2,16 @@
 
 Reproduces DecodeEngine's slot/step/heartbeat/cancel bookkeeping with a
 deterministic token function instead of a forward pass, so fleet-serving
-invariants (batched >= 2x serial, mid-bundle quality, exactly-once decode)
-run in milliseconds in tier-1.  Shared by ``test_fleet.py`` and
-``test_cluster.py``.
+invariants (proportional allotment, mid-bundle quality, exactly-once decode)
+run in milliseconds in tier-1.  Shared by the serving, fleet, stream,
+disaggregation and cluster tests.
 """
 
 import dataclasses
 
 import numpy as np
 
-from repro.serve import KVHandoff, Request
+from repro.serve import FleetServer, KVHandoff, Replica, Request
 
 
 def stub_token(rid: int, k: int) -> int:
@@ -184,3 +184,11 @@ def mk_requests(n, prompt_len=2, max_new=6):
 
 def expected_tokens(r: Request) -> list[int]:
     return [stub_token(r.rid, k) for k in range(r.max_new_tokens)]
+
+
+def stub_fleet(specs, **kw):
+    """A FleetServer over stub engines.  ``specs``: (name, step clock,
+    slots) per replica.  Returns the server and its engines."""
+    replicas = [Replica(n, p) for n, p, _ in specs]
+    engines = {n: StubEngine(max_batch=b, name=n) for n, _, b in specs}
+    return FleetServer(replicas, engines, **kw), engines

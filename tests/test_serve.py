@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from stub_engine import mk_requests, stub_fleet
 
 from repro.cluster import Cluster, ServeJob
 from repro.core import TimelineEvent
@@ -17,7 +18,6 @@ from repro.obs import trace as obs_trace
 from repro.serve import (
     DecodeEngine,
     FleetServer,
-    HomogenizedDispatcher,
     Replica,
     Request,
 )
@@ -193,70 +193,52 @@ def test_prefill_first_token_is_the_host_argmax(length):
 
 
 def test_dispatch_proportional_after_learning():
-    d = HomogenizedDispatcher([Replica("fast", 10.0), Replica("slow", 2.0)])
-    res = None
+    """From neutral priors, heartbeats teach the tracker the replicas' step
+    clocks: after a few waves the allotment follows them."""
+    srv, _ = stub_fleet([("fast", 10.0, 1), ("slow", 2.0, 1)],
+                        max_queue_depth=120)
+    rep = None
     for _ in range(6):
-        res = d.dispatch(120)
-    assert res.shares["fast"] > 4 * res.shares["slow"]
+        rep = srv.serve(mk_requests(120))
+    shares = rep.bundles[0].shares
+    assert shares["fast"] > 4 * shares["slow"]
 
 
 def test_dispatch_homogenized_beats_equal_makespan():
-    reps = [Replica("a", 10.0), Replica("b", 5.0), Replica("c", 1.0)]
-    dh = HomogenizedDispatcher(reps, homogenize=True)
-    de = HomogenizedDispatcher(reps, homogenize=False)
+    specs = [("a", 10.0, 1), ("b", 5.0, 1), ("c", 1.0, 1)]
+    dh, _ = stub_fleet(specs, homogenize=True, max_queue_depth=160)
+    de, _ = stub_fleet(specs, homogenize=False, max_queue_depth=160)
     for _ in range(5):
-        rh = dh.dispatch(160)
-        re_ = de.dispatch(160)
-    assert rh.makespan < re_.makespan
+        rh = dh.serve(mk_requests(160))
+        re_ = de.serve(mk_requests(160))
+    assert rh.sim_time_s < re_.sim_time_s
     # homogenization line: drain times nearly equal across replicas
-    ts = [t for t in rh.per_replica_time.values() if t > 0]
+    ts = [t for t in rh.bundles[0].worker_busy.values() if t > 0]
     assert max(ts) / min(ts) < 1.25
 
 
 def test_dispatch_replica_failure():
-    d = HomogenizedDispatcher([Replica("a", 4.0), Replica("b", 4.0)])
-    d.dispatch(64)
-    d.kill("b")
-    res = d.dispatch(64)
-    assert res.shares == {"a": 64}
+    srv, _ = stub_fleet([("a", 4.0, 1), ("b", 4.0, 1)], max_queue_depth=64)
+    srv.serve(mk_requests(64))
+    srv.kill("b")
+    rep = srv.serve(mk_requests(64))
+    assert rep.bundles[0].shares == {"a": 64}
 
 
 def test_dispatch_midbundle_degradation_rehomogenizes():
     """A replica degrading *during* a bundle: the runtime migrates its queued
     requests, so the bundle still drains near the homogenization line."""
-    from repro.core import TimelineEvent
-
-    d = HomogenizedDispatcher([Replica("a", 4.0), Replica("b", 4.0)])
+    srv, _ = stub_fleet([("a", 4.0, 1), ("b", 4.0, 1)], max_queue_depth=400)
     for _ in range(3):
-        d.dispatch(160)  # learn true perfs
-    res = d.dispatch(
-        400, timeline=(TimelineEvent(5.0, "perf", "b", perf=1.0),)
+        srv.serve(mk_requests(160))  # learn true perfs
+    # 400 requests of 7 steps at 8 steps/s plan 350 s: the drop lands 10% in
+    rep = srv.serve(
+        mk_requests(400), timeline=(TimelineEvent(35.0, "perf", "b", perf=1.0),)
     )
+    res = rep.bundles[0]
     assert res.n_migrated > 0
     assert res.quality <= 1.1, res
     assert res.shares["a"] > res.shares["b"]
-
-
-@pytest.mark.slow  # compiles two engines (~7s); covered by the slow tier
-def test_dispatch_to_real_engines_exactly_once_serial():
-    """Real DecodeEngines behind the runtime (per-request-serial baseline):
-    every request decoded exactly once with outputs equal to the
-    single-engine greedy reference, even though requests migrate between
-    replica queues."""
-    model, params = tiny_model()
-    engines = {
-        "fast": DecodeEngine(model, params, max_batch=2, max_seq=32, name="fast"),
-        "slow": DecodeEngine(model, params, max_batch=2, max_seq=32, name="slow"),
-    }
-    d = HomogenizedDispatcher([Replica("fast", 8.0), Replica("slow", 2.0)])
-    reqs = [Request(rid=i, prompt=[1 + i, 7, 2], max_new_tokens=4) for i in range(8)]
-    res, run = d.dispatch_to_engines(engines, reqs, batched=False)
-    assert sum(res.shares.values()) == 8
-    assert res.shares["fast"] > res.shares["slow"]
-    for r in reqs:
-        assert len(r.out_tokens) == 4
-        ref = _greedy_reference(model, params, r.prompt, 4, 32)
-        assert r.out_tokens == ref, (r.rid, r.out_tokens, ref)
 
 
 @pytest.mark.slow  # compiles two engines; covered by the slow tier

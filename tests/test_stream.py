@@ -134,10 +134,10 @@ def test_wave_plan_caps_per_replica_initial_queue():
     max_queue_depth.  The plan cap enforces the depth per replica."""
     server = mk_server([("fast", 8.0, 2), ("slow", 1.0, 2)],
                        max_queue_depth=4)
-    now = server.dispatcher.clock
+    now = server.runtime.clock
     server.tracker.rejoin("fast", 8.0, now)
     server.tracker.rejoin("slow", 1.0, now)
-    uncapped = server.dispatcher.runtime.plan(8)
+    uncapped = server.runtime.plan(8)
     by_name = dict(zip(uncapped.workers, uncapped.shares))
     assert by_name["fast"] > 4, "precondition: the homogenized share must breach the cap"
     capped = server._wave_plan(8)
@@ -161,7 +161,7 @@ def test_wave_serve_respects_per_replica_depth():
     all requests decode exactly once."""
     server = mk_server([("fast", 8.0, 2), ("slow", 1.0, 2)],
                        max_queue_depth=3)
-    now = server.dispatcher.clock
+    now = server.runtime.clock
     server.tracker.rejoin("fast", 8.0, now)
     server.tracker.rejoin("slow", 1.0, now)
     reqs = mk_requests(6, max_new=4)
@@ -252,7 +252,7 @@ def test_stream_survives_mid_stream_halve():
     """The acceptance shape: a mid-stream perf halving migrates load and the
     survivors still homogenize (quality <= 1.3)."""
     server = mk_server([("r0", 4.0, 2), ("r1", 4.0, 2)], max_queue_depth=8)
-    now = server.dispatcher.clock
+    now = server.runtime.clock
     for n in ("r0", "r1"):
         server.tracker.rejoin(n, 8.0, now)  # rate units: perf x slots
     reqs = mk_requests(24, max_new=6)

@@ -5,7 +5,8 @@ sub-millisecond jitted calls):
 
   - seam neutrality: ``Cluster(backend='sim')`` is the default and produces
     field-for-field identical reports (the raw runtime likewise with an
-    explicit ``SimBackend`` / ``ExecutionBackend``),
+    explicit ``SimBackend`` / ``ExecutionBackend``); only a ``measured``
+    backend's step clock reaches a fleet's executors,
   - actionable validation: unknown ``backend`` / ``eta_mode`` strings and
     non-backend objects raise with the valid choices in the message,
   - wallclock smoke: measured speedup > 0, backend provenance on the report,
@@ -83,6 +84,33 @@ def test_raw_runtime_explicit_sim_backend_identical():
     assert t0 == t1 == t2
 
 
+@pytest.mark.parametrize("make_backend, measured", [
+    (lambda: None, False),
+    (SimBackend, False),
+    (ExecutionBackend, False),
+    (lambda: WallclockBackend(calibration_reps=4), True),
+], ids=["none", "sim", "base", "wallclock"])
+def test_measured_backend_alone_sets_the_executor_step_clock(
+        make_backend, measured):
+    # ``measured`` is the one property that says durations are wall time;
+    # only then do a fleet's executors read the backend's step clock.
+    from stub_engine import mk_requests, stub_fleet
+
+    backend = make_backend()
+    srv, _ = stub_fleet([("pf", 2.0, 2), ("dc", 4.0, 2)], backend=backend)
+    assert srv.runtime.backend.measured is measured
+    roles = {"pf": "prefill", "dc": "decode"}
+    for ex in (srv._executor(mk_requests(2)),
+               srv._executor(mk_requests(2), roles=roles)):
+        w = srv.runtime.workers["dc"]
+        if measured:
+            assert ex.step_clock == backend.step_clock
+            assert ex.step_seconds(w) == backend.step_clock(w)
+        else:
+            assert ex.step_clock is None
+            assert ex.step_seconds(w) == 1.0 / 4.0
+
+
 def test_eta_mode_recompute_matches_incremental():
     job = SimJob(size=64)
     inc = Cluster(FLEET, eta_mode="incremental").simulate(job)
@@ -149,14 +177,20 @@ def test_wallclock_shared_across_jobs():
 def test_wallclock_step_clock_starts_afresh_each_job():
     # A job's step clock is learned from its own ticks: a slow tick of the
     # last job (a compile, say) does not set the next job's first ticks.
+    backend = WallclockBackend(calibration_reps=4)
+
     class Slow:
+        # An executor wired to the backend's step clock, as the fleet
+        # server wires its engine executors.
         uniform_cost = 1.0
 
         def tick(self, worker, now_s):
             time.sleep(0.05)
             return []
 
-    backend = WallclockBackend(calibration_reps=4)
+        def tick_s(self, worker, now_s):
+            return backend.step_clock(worker)
+
     ex, w = Slow(), SimWorker("w0", 1.0)
     backend.begin_job(ex, 1, 0.0)
     backend.timed_tick(ex, w, 0.0)
